@@ -14,32 +14,32 @@ Rows (tracked perf trajectory):
                                 step (0 on the fused path) and whether the
                                 greedy outputs matched bit-for-bit.
 
-Multi-device CPU needs ``--xla_force_host_platform_device_count`` set
-BEFORE jax initializes, so the measured section runs in a subprocess (the
-same idiom as tests/test_mesh_parallel.py); the child prints one JSON line
-the parent turns into rows. Virtual host devices share one physical CPU —
-these rows pin plumbing overhead and sync behavior, not real speedups.
+The measurement runs in this process on ``jax.devices()`` and fails when
+there are fewer than four: a chip belongs to the one process that holds it,
+so no child process is started. CPU mesh coverage lives in
+tests/test_mesh_parallel.py (virtual devices, parity only, no timings).
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 
 MESH_DEVICES = 4
 MESH_SHAPE = (2, 2)
 
 
-def _child() -> None:
+def _measure() -> dict:
     import time
 
     import jax
     import numpy as np
     import jax.numpy as jnp
 
-    from repro.distributed.compat import make_mesh
+    from repro.distributed.sharding import make_mesh
 
+    if jax.device_count() < MESH_DEVICES:
+        raise RuntimeError(
+            f"bench_mesh needs {MESH_DEVICES} devices, found "
+            f"{jax.device_count()} ({jax.devices()[0].platform})")
     smoke = os.environ.get("REPRO_BENCH_SERVE_SMOKE") == "1"
     mesh = make_mesh(MESH_SHAPE, ("data", "model"))
     out: dict = {}
@@ -131,27 +131,13 @@ def _child() -> None:
         "requests": requests,
         "match": all(np.array_equal(a, b) for a, b in zip(souts, outs)),
     }
-    print("JSON:" + json.dumps(out))
+    return out
 
 
 def run() -> None:
     from benchmarks.common import emit
 
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={MESH_DEVICES}"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = (os.path.join(root, "src")
-                         + os.pathsep + env.get("PYTHONPATH", root))
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_mesh", "--child"],
-        capture_output=True, text=True, env=env, cwd=root, timeout=1800)
-    payload = next((ln[len("JSON:"):] for ln in proc.stdout.splitlines()
-                    if ln.startswith("JSON:")), None)
-    if proc.returncode != 0 or payload is None:
-        raise RuntimeError("mesh child failed:\n"
-                           + (proc.stdout + proc.stderr)[-3000:])
-    data = json.loads(payload)
+    data = _measure()
 
     t = data["train"]
     emit("train/packed_shard", t["us_shard"],
@@ -179,7 +165,4 @@ def run() -> None:
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
-        _child()
-    else:
-        run()
+    run()

@@ -37,6 +37,9 @@ MODULES = {
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = sys.argv[1:] or list(MODULES)
     print("name,us_per_call,derived")
     failures = []

@@ -173,7 +173,7 @@ _COLLECTIVES = frozenset({
     "shard_map", "psum", "pmax", "pmin", "pmean", "all_gather", "ppermute",
     "all_to_all", "psum_scatter", "axis_index",
 })
-# mesh constructors (suffix-matched: jax.sharding.Mesh, compat.make_mesh,
+# mesh constructors (suffix-matched: jax.sharding.Mesh, sharding.make_mesh,
 # launch.mesh.make_host_mesh all count)
 _MESH_CTORS = frozenset({"Mesh", "make_mesh", "make_host_mesh"})
 

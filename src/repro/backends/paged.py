@@ -33,16 +33,16 @@ def _plan(shape: MixerShape, mesh, dtype) -> MixerPlan:
 
 
 def pack_pages(x, block: int):
-    """[B, H, N, D] -> ([B*P, block, H, D] pages, [B, P] identity page table).
+    """[B, H, N, D] -> ([B*P, H, block, D] pages, [B, P] identity page table).
     The on-the-fly paging dense call sites use; the serving pool already
     holds this layout."""
     b, h, n, d = x.shape
     p = -(-n // block)
-    xt = jnp.moveaxis(x, 1, 2)  # [B, N, H, D]
     pad = p * block - n
     if pad:
-        xt = jnp.pad(xt, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    pages = xt.reshape(b * p, block, h, d)
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    pages = jnp.moveaxis(x.reshape(b, h, p, block, d), 2, 1)  # [B, P, H, block, D]
+    pages = pages.reshape(b * p, h, block, d)
     pt = jnp.arange(b * p, dtype=jnp.int32).reshape(b, p)
     return pages, pt
 
@@ -133,18 +133,16 @@ def _plan_shard(shape: MixerShape, mesh, dtype) -> MixerPlan:
 def _run_shard(plan: MixerPlan, q, k, v):
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
     mesh = plan.params["mesh"]
     ax = plan.params["shard_axes"]
     axe = ax[0] if len(ax) == 1 else tuple(ax)
     inner = MixerPlan("paged", {"block": plan.params.get("block", DEFAULT_BLOCK)})
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_: _run(inner, q_, k_, v_),
         mesh=mesh,
         in_specs=(P(), P(axe, None, None, None), P(axe, None, None, None)),
         out_specs=P(axe, None, None, None),
-        check_rep=False,  # no replication rule exists for pallas_call
+        check_vma=False,  # no replication rule exists for pallas_call
     )
     return fn(q, k, v)
 

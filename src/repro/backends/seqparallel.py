@@ -17,9 +17,6 @@ from repro.core.dispatch import (
 )
 
 
-from repro.distributed.compat import shard_map as _shard_map
-
-
 def _plan_sp(shape: MixerShape, mesh, dtype) -> MixerPlan:
     if mesh is None:
         raise ValueError(
@@ -45,7 +42,7 @@ def _run_sp(plan: MixerPlan, q, k, v):
 
     mesh, seq_axes = plan.params["mesh"], plan.params["seq_axes"]
     axis_name = seq_axes if isinstance(seq_axes, str) else tuple(seq_axes)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_: flare_mixer_seqparallel(q_, k_, v_, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(), P(None, None, axis_name, None), P(None, None, axis_name, None)),
@@ -61,7 +58,7 @@ def _run_sp2d(plan: MixerPlan, q, k, v):
 
     mesh = plan.params["mesh"]
     seq_axes, lat_axes = plan.params["seq_axes"], plan.params["lat_axes"]
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_: flare_mixer_seqlat(q_, k_, v_, seq_axis=seq_axes,
                                               lat_axis=lat_axes),
         mesh=mesh,

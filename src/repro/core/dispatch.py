@@ -351,7 +351,7 @@ def _probe_mesh():
     """A minimal (1, 1) host mesh for the eligibility columns — one device
     suffices: eligibility is a capability question, not a placement one."""
     try:
-        from repro.distributed.compat import make_mesh
+        from repro.distributed.sharding import make_mesh
 
         return make_mesh((1, 1), ("data", "model"))
     except Exception:  # noqa: BLE001 — no devices at all; column shows "?"

@@ -19,6 +19,14 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
+def make_mesh(axis_shapes, axis_names) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types. jax defaults to Explicit
+    axes; every mesh here is consumed by NamedSharding placement and
+    ``shard_map`` bodies written for compiler-propagated (Auto) sharding."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto)
+
+
 def _axis_size(mesh: Mesh, axis) -> int:
     if axis is None:
         return 1

@@ -48,12 +48,22 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 LANE = 128
+# Scoped-VMEM cap for these launches. The backward sweep keeps ~10 live
+# [pack*Mp, block_n] f32 temporaries; at M=2048 and block_n=256 that exceeds
+# Mosaic's 16 MiB default, while v5e has 128 MiB of VMEM per core.
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _vmem(shape, dtype):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.VMEM(shape, dtype)
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -197,8 +207,8 @@ def _fused_fwd_kernel(q_ref, k_ref, v_ref, y_ref, z_ref, mx_ref, den_ref,
             zbd = jnp.where(bd, num_scr[...] / den_scr[...][:, None], 0.0)
             zbd_scr[...] = zbd
             z_ref[0] = _compact_block_diag(cfg, zbd)
-            mx_ref[0] = mx_scr[...]
-            den_ref[0] = den_scr[...]
+            mx_ref[0, 0] = mx_scr[...]
+            den_ref[0, 0] = den_scr[...]
 
     @pl.when(phase == 1)
     def _decode():
@@ -238,14 +248,14 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
             # any flush can happen
             pl.BlockSpec((1, bn, wl), lambda g_, p_, n_: (g_, p_ * n_, 0)),
             pl.BlockSpec((1, mp, wl), lambda g_, p_, n_: (g_, 0, 0)),
-            pl.BlockSpec((1, s_rows), lambda g_, p_, n_: (g_, 0)),
-            pl.BlockSpec((1, s_rows), lambda g_, p_, n_: (g_, 0)),
+            pl.BlockSpec((1, 1, s_rows), lambda g_, p_, n_: (g_, 0, 0)),
+            pl.BlockSpec((1, 1, s_rows), lambda g_, p_, n_: (g_, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, np_, wl), v_p.dtype),       # y
             jax.ShapeDtypeStruct((g, mp, wl), jnp.float32),      # Z (compact)
-            jax.ShapeDtypeStruct((g, s_rows), jnp.float32),      # encode max
-            jax.ShapeDtypeStruct((g, s_rows), jnp.float32),      # encode den
+            jax.ShapeDtypeStruct((g, 1, s_rows), jnp.float32),   # encode max
+            jax.ShapeDtypeStruct((g, 1, s_rows), jnp.float32),   # encode den
         ],
         scratch_shapes=[
             _vmem((s_rows,), jnp.float32),        # running max
@@ -254,6 +264,7 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
             _vmem((s_rows, wl), jnp.float32),     # Z block-diagonal (lives
                                                   # across the phase switch)
         ],
+        compiler_params=_compiler_params(),
         interpret=cfg.interpret,
     )(q_p, k_p, v_p)
 
@@ -306,7 +317,7 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref
         dy = dy_ref[0].astype(jnp.float32)
         s = _scores(cfg, qbd, k, n_idx)
         # encode weights from saved stats (flash recomputation)
-        a = jnp.exp(s - mx_ref[0][:, None]) / den_ref[0][:, None]
+        a = jnp.exp(s - mx_ref[0, 0][:, None]) / den_ref[0, 0][:, None]
         ok = _token_ok(cfg, s.shape, n_idx)
         if ok is not None:
             a = jnp.where(ok, a, 0.0)
@@ -366,8 +377,8 @@ def _bwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den, y_p, dy_p):
             both,                         # k: scores recomputed in both sweeps
             ph1,                          # v: only dA in sweep 2
             per_group((mp, wl)),          # z compact
-            per_group((s_rows,)),         # encode max
-            per_group((s_rows,)),         # encode den
+            per_group((1, s_rows)),       # encode max
+            per_group((1, s_rows)),       # encode den
             ph1,                          # y: only delta_dec in sweep 2
             both,                         # dy: dZ in sweep 1, dS_dec in sweep 2
         ],
@@ -386,6 +397,7 @@ def _bwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den, y_p, dy_p):
             _vmem((s_rows, wl), jnp.float32),   # dq accumulator
             _vmem((s_rows,), jnp.float32),      # delta_enc
         ],
+        compiler_params=_compiler_params(),
         interpret=cfg.interpret,
     )(q_p, k_p, v_p, z, mx, den, y_p, dy_p)
 

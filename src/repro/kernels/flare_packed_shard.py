@@ -45,12 +45,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.kernels.flare_packed import (
     LANE,
     NEG_INF,
     _bd_mask,
     _compact_block_diag,
+    _compiler_params,
     _decode_weights,
     _expand_block_diag,
     _pack_heads,
@@ -84,7 +84,7 @@ def _spec_entry(axes: Tuple[str, ...]):
 
 
 def _lane_expand(cfg: _PackedCfg, r: jax.Array, wl: int, fill) -> jax.Array:
-    """Per-latent-row values [G, S] -> the packed-compact layout [G, Mp, Wl]
+    """Per-latent-row values [G, 1, S] -> the packed-compact layout [G, Mp, Wl]
     (row s = p*Mp + m lands on latent m's lanes of head p; lane padding gets
     ``fill`` so it divides/multiplies to an exact no-op)."""
     g = r.shape[0]
@@ -135,8 +135,8 @@ def _enc_stats_kernel(q_ref, k_ref, v_ref, num_ref, mx_ref, den_ref,
     @pl.when(n_idx == n_blocks - 1)
     def _finish():
         num_ref[0] = _compact_block_diag(cfg, jnp.where(bd, num_scr[...], 0.0))
-        mx_ref[0] = mx_scr[...]
-        den_ref[0] = den_scr[...]
+        mx_ref[0, 0] = mx_scr[...]
+        den_ref[0, 0] = den_scr[...]
 
 
 def _enc_stats_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
@@ -155,19 +155,20 @@ def _enc_stats_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
         ],
         out_specs=[
             pl.BlockSpec((1, mp, wl), lambda g_, n_: (g_, 0, 0)),
-            pl.BlockSpec((1, s_rows), lambda g_, n_: (g_, 0)),
-            pl.BlockSpec((1, s_rows), lambda g_, n_: (g_, 0)),
+            pl.BlockSpec((1, 1, s_rows), lambda g_, n_: (g_, 0, 0)),
+            pl.BlockSpec((1, 1, s_rows), lambda g_, n_: (g_, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, mp, wl), jnp.float32),   # numerator
-            jax.ShapeDtypeStruct((g, s_rows), jnp.float32),   # local max
-            jax.ShapeDtypeStruct((g, s_rows), jnp.float32),   # local den
+            jax.ShapeDtypeStruct((g, 1, s_rows), jnp.float32),  # local max
+            jax.ShapeDtypeStruct((g, 1, s_rows), jnp.float32),  # local den
         ],
         scratch_shapes=[
             _vmem((s_rows,), jnp.float32),
             _vmem((s_rows,), jnp.float32),
             _vmem((s_rows, wl), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=cfg.interpret,
     )(q_p, k_p, v_p)
 
@@ -205,6 +206,7 @@ def _decode_launch(cfg: _PackedCfg, gh: int, q_p, k_p, z, out_dtype):
         ],
         out_specs=[pl.BlockSpec((1, bn, wl), lambda g_, n_: (g_, n_, 0))],
         out_shape=[jax.ShapeDtypeStruct((g, np_, wl), out_dtype)],
+        compiler_params=_compiler_params(),
         interpret=cfg.interpret,
     )(q_p, k_p, z)[0]
 
@@ -254,6 +256,7 @@ def _dz_launch(cfg: _PackedCfg, gh: int, q_p, k_p, dy_p):
         out_specs=[pl.BlockSpec((1, mp, wl), lambda g_, n_: (g_, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((g, mp, wl), jnp.float32)],
         scratch_shapes=[_vmem((s_rows, wl), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=cfg.interpret,
     )(q_p, k_p, dy_p)[0]
 
@@ -287,7 +290,7 @@ def _grads_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref,
     s = _scores(cfg, qbd, k, n_idx)
     # encode weights from the GLOBAL saved stats: a is each local token's
     # weight in the full-sequence encode softmax
-    a = jnp.exp(s - mx_ref[0][:, None]) / den_ref[0][:, None]
+    a = jnp.exp(s - mx_ref[0, 0][:, None]) / den_ref[0, 0][:, None]
     ok = _token_ok(cfg, s.shape, n_idx)
     if ok is not None:
         a = jnp.where(ok, a, 0.0)
@@ -337,8 +340,8 @@ def _grads_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den,
             stream,                       # k
             stream,                       # v
             per_group((mp, wl)),          # z compact (global)
-            per_group((s_rows,)),         # global encode max
-            per_group((s_rows,)),         # global encode den
+            per_group((1, s_rows)),       # global encode max
+            per_group((1, s_rows)),       # global encode den
             stream,                       # y
             stream,                       # dy
             per_group((mp, wl)),          # dz compact (global)
@@ -357,6 +360,7 @@ def _grads_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den,
             _vmem((s_rows, wl), jnp.float32),
             _vmem((s_rows,), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=cfg.interpret,
     )(q_p, k_p, v_p, z, mx, den, y_p, dy_p, dz)
 
@@ -375,9 +379,9 @@ def _combine_stats(cfg: _PackedCfg, axes, num, mx, den):
     N. On a 1-shard axis every step is an exact no-op (scale == 1.0)."""
     wl = num.shape[-1]
     gmx = lax.pmax(mx, axes)
-    scale = jnp.exp(mx - gmx)                                # [G, S]
+    scale = jnp.exp(mx - gmx)                                # [G, 1, S]
     num_g = lax.psum(num * _lane_expand(cfg, scale, wl, 1.0), axes)
-    den_g = lax.psum(den * scale, axes)                      # [G, S]
+    den_g = lax.psum(den * scale, axes)                      # [G, 1, S]
     z = num_g / _lane_expand(cfg, den_g, wl, 1.0)
     return z, gmx, den_g
 
@@ -498,13 +502,13 @@ def flare_mixer_packed_shard(
     body = functools.partial(_local_mixer, axes=seq, pack=pack,
                              block_n=block_n, interpret=bool(interpret))
     lat_e, seq_e = _spec_entry(lat), _spec_entry(seq)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(lat_e, None, None),
                   P(None, lat_e, seq_e, None),
                   P(None, lat_e, seq_e, None)),
         out_specs=P(None, lat_e, seq_e, None),
-        check_rep=False,  # no replication rule exists for pallas_call
+        check_vma=False,  # no replication rule exists for pallas_call
     )
     return fn(q, k, v)

@@ -2,7 +2,7 @@
 (DESIGN.md §4 "Paged pool").
 
 The serve-side paged pool (`repro.serve.pool`) stores token-axis cache
-leaves as ``[num_blocks(+trash), block, H, D]`` physical pages addressed
+leaves as ``[num_blocks(+trash), H, block, D]`` physical pages addressed
 through a per-slot page table. At high slot counts, decode throughput is
 HBM-bound on cache reads (FlashAttention's IO framing — PAPERS.md): a
 dense pool streams ``slots x capacity`` rows per step whether or not they
@@ -11,9 +11,10 @@ hold tokens, while this kernel DMAs **only the pages a slot has mapped**
 (``pltpu.PrefetchScalarGridSpec``) so each grid step's BlockSpec index_map
 picks the physical page to fetch, vLLM-style.
 
-Schedule: grid ``(B, H, P)`` with the page dimension innermost; running
-(max, den, acc) flash scratch across pages; rows past ``lengths[b]`` are
-masked (the same validity contract as ``models.attention
+Schedule: grid ``(B, P)`` with the page dimension innermost; each step
+fetches one physical page for all heads and loops over the heads inside,
+with running (max, den, acc) flash scratch per head across pages. Rows
+past ``lengths[b]`` are masked (the same validity contract as ``models.attention
 .decode_valid_mask``, so garbage in partially written or still-unmapped
 pages — which the pool points at the trash sink — is invisible).
 
@@ -27,7 +28,7 @@ The query axis G generalizes the consumer:
     block-paged storage.
 
 Two optional extensions serve the quantized pool and MLA:
-  - ``k_scale``/``v_scale`` [NB, block, H]: per-token-row dequant scales
+  - ``k_scale``/``v_scale`` [NB, H, block]: per-token-row dequant scales
     (serve.pool.quant). Dequant happens *inside* the kernel — scores are
     ``(q k_int^T) * k_scale[t]`` and the value reduction folds ``v_scale``
     into the probabilities, so int8/fp8 pages are never materialized wide.
@@ -35,12 +36,11 @@ Two optional extensions serve the quantized pool and MLA:
     ``s += q2 k2^T`` — the MLA absorbed decode (q_abs·c + q_rope·k_rope
     over the same softmax, value = the latents themselves).
 
-CPU/GPU run in interpret mode (ci parity tests) — un-padded, since lane
-tiling is a TPU constraint. TPU compiles: D should be 128-lane padded and
-``block`` a multiple of 8 — the wrapper pads D (and G to a sublane
-multiple) but cannot repack pages, so pick ``block_size`` accordingly when
-targeting TPU (per-row scale refs carry a size-1 lane and may need a
-layout pass there; interpret mode is the supported CI path).
+Every block spans whole trailing dims — ``(block, D)`` of a page,
+``(H, block)`` of its scales, ``(G, D)`` of the queries — so Mosaic's
+(8, 128) tiling rule holds for any head count, head dim, page size and
+payload dtype with no padding (tests/test_tpu_compile.py compiles it at
+qwen2_1_5b widths). CPU runs it in interpret mode (the parity tests).
 """
 from __future__ import annotations
 
@@ -52,7 +52,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
-LANE = 128
 
 
 def _vmem(shape, dtype):
@@ -61,8 +60,8 @@ def _vmem(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def _paged_kernel(pt_ref, len_ref, *refs, block, pages, scale, has_ks, has_vs,
-                  has_q2, has_k2s):
+def _paged_kernel(pt_ref, len_ref, *refs, block, pages, heads, scale, has_ks,
+                  has_vs, has_q2, has_k2s):
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     ks_ref = next(it) if has_ks else None
@@ -76,7 +75,7 @@ def _paged_kernel(pt_ref, len_ref, *refs, block, pages, scale, has_ks, has_vs,
     fused = has_ks or has_vs or has_q2 or q_ref.dtype != k_ref.dtype
 
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
@@ -84,92 +83,103 @@ def _paged_kernel(pt_ref, len_ref, *refs, block, pages, scale, has_ks, has_vs,
         den_scr[...] = jnp.zeros_like(den_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0]            # [G, D]
-    k = k_ref[0, :, 0, :]      # [block, D] — the page the index_map gathered
-    v = v_ref[0, :, 0, :]
-    if fused:
-        # dequant-on-read path: payloads may be int8/fp8 rows, so the dot
-        # runs in f32 and per-row scales fold in AFTER the contraction
-        # (s[g,t] = (q·k_int)[g,t] * scale[t] — scales are per token row)
-        s = jax.lax.dot_general(q.astype(jnp.float32), k.astype(jnp.float32),
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if has_ks:
-            s = s * ks_ref[0, :, 0][None, :]
-        if has_q2:
-            s2 = jax.lax.dot_general(
-                q2_ref[0, 0].astype(jnp.float32),
-                k2_ref[0, :, 0, :].astype(jnp.float32),
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-            if has_k2s:
-                s2 = s2 * k2s_ref[0, :, 0][None, :]
-            s = s + s2
-    else:
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [G, block]
-    if scale != 1.0:
-        # post-dot in f32 — the same op order as the jnp decode paths
-        # (scores * scale), which is what keeps the routes token-exact
-        s = s * scale
-    # rows at global index >= lengths[b] are unwritten/garbage (incl. the
-    # whole trash sink a not-yet-mapped page points at)
-    tok = pi * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    ok = tok < len_ref[b]
-    s = jnp.where(ok, s, NEG_INF)
+    # the grid step holds one physical page for every head; heads are a
+    # static loop so each page row is a plain [block, D] tile
+    for hh in range(heads):
+        q = q_ref[0, hh]            # [G, D]
+        k = k_ref[0, hh]            # [block, D]
+        v = v_ref[0, hh]
+        if fused:
+            # dequant-on-read path: payloads may be int8/fp8 rows, so the
+            # dot runs in f32 and per-row scales fold in AFTER the
+            # contraction (s[g,t] = (q·k_int)[g,t] * scale[t])
+            s = jax.lax.dot_general(q.astype(jnp.float32), k.astype(jnp.float32),
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if has_ks:
+                s = s * ks_ref[0, pl.ds(hh, 1), :]
+            if has_q2:
+                s2 = jax.lax.dot_general(
+                    q2_ref[0, hh].astype(jnp.float32),
+                    k2_ref[0, hh].astype(jnp.float32),
+                    (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                if has_k2s:
+                    s2 = s2 * k2s_ref[0, pl.ds(hh, 1), :]
+                s = s + s2
+        else:
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)  # [G, block]
+        if scale != 1.0:
+            # post-dot in f32 — the same op order as the jnp decode paths
+            # (scores * scale), which is what keeps the routes token-exact
+            s = s * scale
+        # rows at global index >= lengths[b] are unwritten/garbage (incl.
+        # the whole trash sink a not-yet-mapped page points at)
+        tok = pi * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ok = tok < len_ref[b]
+        s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = max_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-    den_scr[...] = den_scr[...] * alpha + jnp.sum(p, axis=-1)
-    if fused:
-        if has_vs:
-            p = p * vs_ref[0, :, 0][None, :]
-        pv = jax.lax.dot_general(p, v.astype(jnp.float32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    else:
-        pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
-    max_scr[...] = m_new
+        m_prev = max_scr[hh]                                   # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        den_scr[hh] = den_scr[hh] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if fused:
+            if has_vs:
+                p = p * vs_ref[0, pl.ds(hh, 1), :]
+            pv = jax.lax.dot_general(p, v.astype(jnp.float32),
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        else:
+            pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        acc_scr[hh] = acc_scr[hh] * alpha + pv
+        max_scr[hh] = m_new
 
     @pl.when(pi == pages - 1)
     def _finish():
-        den = jnp.maximum(den_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / den[:, None]).astype(o_ref.dtype)
+        for hh in range(heads):
+            den = jnp.maximum(den_scr[hh], 1e-30)
+            o_ref[0, hh] = (acc_scr[hh] / den).astype(o_ref.dtype)
 
 
-def paged_attention_pallas(
+def paged_attention(
     q: jax.Array,          # [B, H, G, D]
-    k_pages: jax.Array,    # [NB, block, H, D] physical pages (+ trash row)
-    v_pages: jax.Array,    # [NB, block, H, D]
+    k_pages: jax.Array,    # [NB, H, block, D] physical pages (+ trash row)
+    v_pages: jax.Array,    # [NB, H, block, D]
     page_table: jax.Array,  # [B, P] int32 physical ids (trash for unmapped)
     lengths: jax.Array,    # [B] int32 valid tokens per lane
     *,
     scale: float = 1.0,
-    k_scale: Optional[jax.Array] = None,   # [NB, block, H] f32 row scales
-    v_scale: Optional[jax.Array] = None,   # [NB, block, H]
+    k_scale: Optional[jax.Array] = None,   # [NB, H, block] f32 row scales
+    v_scale: Optional[jax.Array] = None,   # [NB, H, block]
     q2: Optional[jax.Array] = None,        # [B, H, G, D2] second score term
-    k2_pages: Optional[jax.Array] = None,  # [NB, block, H, D2]
-    k2_scale: Optional[jax.Array] = None,  # [NB, block, H]
+    k2_pages: Optional[jax.Array] = None,  # [NB, H, block, D2]
+    k2_scale: Optional[jax.Array] = None,  # [NB, H, block]
     out_dtype=None,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Softmax(scale * (q k^T [+ q2 k2^T]) over the mapped, valid tokens) @ v,
     reading K/V page-by-page through the page table, dequantizing rows
-    in-register when scales are given. Lanes with length 0 return 0."""
+    in-register when scales are given. Lanes with length 0 return 0.
+    ``interpret`` defaults to True off TPU (the CPU test path)."""
     from jax.experimental.pallas import tpu as pltpu
 
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    page_table = page_table.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
     bsz, h, g, d = q.shape
-    block = k_pages.shape[1]
+    block = k_pages.shape[2]
     pages = page_table.shape[1]
-    q_spec = pl.BlockSpec((1, 1, g, d), lambda b, hh, p, pt, ln: (b, hh, 0, 0))
+    # every block spans whole trailing dims — (block, D) for pages,
+    # (H, block) for scales — which is what Mosaic's (8, 128) tiling rule
+    # accepts for any head count, block size and payload dtype
+    whole = lambda dd: pl.BlockSpec((1, h, g, dd), lambda b, p, pt, ln: (b, 0, 0, 0))
     page_spec = lambda dd: pl.BlockSpec(
-        (1, block, 1, dd), lambda b, hh, p, pt, ln: (pt[b, p], 0, hh, 0))
-    row_spec = pl.BlockSpec((1, block, 1),
-                            lambda b, hh, p, pt, ln: (pt[b, p], 0, hh))
-    in_specs = [q_spec, page_spec(d), page_spec(d)]
+        (1, h, block, dd), lambda b, p, pt, ln: (pt[b, p], 0, 0, 0))
+    row_spec = pl.BlockSpec((1, h, block), lambda b, p, pt, ln: (pt[b, p], 0, 0))
+    in_specs = [whole(d), page_spec(d), page_spec(d)]
     operands = [q, k_pages, v_pages]
     if k_scale is not None:
         in_specs.append(row_spec)
@@ -179,9 +189,7 @@ def paged_attention_pallas(
         operands.append(v_scale)
     if q2 is not None:
         d2 = q2.shape[-1]
-        in_specs += [pl.BlockSpec((1, 1, g, d2),
-                                  lambda b, hh, p, pt, ln: (b, hh, 0, 0)),
-                     page_spec(d2)]
+        in_specs += [whole(d2), page_spec(d2)]
         operands += [q2, k2_pages]
         if k2_scale is not None:
             in_specs.append(row_spec)
@@ -189,18 +197,17 @@ def paged_attention_pallas(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(bsz, h, pages),
+        grid=(bsz, pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b, hh, p, pt, ln: (b, hh, 0, 0)),
+        out_specs=whole(d),
         scratch_shapes=[
-            _vmem((g,), jnp.float32),
-            _vmem((g,), jnp.float32),
-            _vmem((g, d), jnp.float32),
+            _vmem((h, g, 1), jnp.float32),
+            _vmem((h, g, 1), jnp.float32),
+            _vmem((h, g, d), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, block=block, pages=pages,
-                               scale=float(scale),
+                               heads=h, scale=float(scale),
                                has_ks=k_scale is not None,
                                has_vs=v_scale is not None,
                                has_q2=q2 is not None,
@@ -214,63 +221,11 @@ def paged_attention_pallas(
     )(page_table, lengths, *operands)
 
 
-def _pad_axis(x: jax.Array, axis: int, multiple: int) -> jax.Array:
-    pad = (-x.shape[axis]) % multiple
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
-
-
-def paged_attention(
-    q: jax.Array,          # [B, H, G, D]
-    k_pages: jax.Array,    # [NB, block, H, D]
-    v_pages: jax.Array,    # [NB, block, H, D]
-    page_table: jax.Array,  # [B, P] int32
-    lengths: jax.Array,    # [B] int32
-    *,
-    scale: float = 1.0,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-    q2: Optional[jax.Array] = None,
-    k2_pages: Optional[jax.Array] = None,
-    k2_scale: Optional[jax.Array] = None,
-    out_dtype=None,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Padding wrapper (ops.py idiom): D to the 128-lane boundary, G to a
-    sublane multiple; zero columns don't change q.k scores, padded output
-    rows/cols are sliced away. Pages themselves are never repacked. Lane
-    tiling is a TPU constraint, so interpret mode (the CPU/GPU CI path)
-    skips the pads — the decode hot loop then moves exactly the mapped
-    bytes instead of 128-lane-wide copies of tiny heads."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    bsz, h, g, d = q.shape
-    if interpret:
-        qp, kp, vp, q2p, k2p = q, k_pages, v_pages, q2, k2_pages
-    else:
-        qp = _pad_axis(_pad_axis(q, 3, LANE), 2, 8)
-        kp = _pad_axis(k_pages, 3, LANE)
-        vp = _pad_axis(v_pages, 3, LANE)
-        q2p = None if q2 is None else _pad_axis(_pad_axis(q2, 3, LANE), 2, 8)
-        k2p = None if k2_pages is None else _pad_axis(k2_pages, 3, LANE)
-    o = paged_attention_pallas(qp, kp, vp, page_table.astype(jnp.int32),
-                               lengths.astype(jnp.int32), scale=scale,
-                               k_scale=k_scale, v_scale=v_scale,
-                               q2=q2p, k2_pages=k2p, k2_scale=k2_scale,
-                               out_dtype=out_dtype,
-                               interpret=interpret)
-    return o[:, :, :g, :d]
-
-
 def _gather_rows(pages, page_table):
-    """[NB, block, H, ...] + [B, P] -> [B, H, P*block, ...]."""
-    x = pages[page_table]  # [B, P, block, H, ...]
-    bsz, p, blk = x.shape[:3]
-    x = x.reshape((bsz, p * blk) + x.shape[3:])
-    return jnp.moveaxis(x, 2, 1)
+    """[NB, H, block, ...] + [B, P] -> [B, H, P*block, ...]."""
+    x = jnp.moveaxis(pages[page_table], 2, 1)  # [B, H, P, block, ...]
+    bsz, h, p, blk = x.shape[:4]
+    return x.reshape((bsz, h, p * blk) + x.shape[4:])
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, lengths, *,

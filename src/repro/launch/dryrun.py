@@ -67,9 +67,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str) -> dict:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
         t0 = time.time()
         cell = build_cell(cfg, shape, mesh)
-        from repro.distributed.compat import set_mesh
-
-        with mesh, set_mesh(mesh):
+        with mesh, jax.sharding.set_mesh(mesh):
             lowered = jax.jit(
                 cell.fn, in_shardings=cell.in_shardings,
                 out_shardings=cell.out_shardings,

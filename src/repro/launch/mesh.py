@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -9,6 +9,7 @@ import logging
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import TrainConfig
 from repro.configs import get_config, get_smoke_config
 from repro.data.synthetic import TokenStream
@@ -19,6 +20,7 @@ from repro.train.trainer import Trainer
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

@@ -289,7 +289,8 @@ def get_model(cfg: ModelConfig, *, policy=None, mesh=None,
             init=_init,
             loss=_train_guard(
                 lambda p, b: pde.surrogate_loss(p, b, num_heads=cfg.flare_heads,
-                                                policy=train_plan),
+                                                policy=train_plan,
+                                                remat=cfg.remat),
                 train_error),
             forward=lambda p, b: pde.surrogate_forward(p, b["x"],
                                                        num_heads=cfg.flare_heads,

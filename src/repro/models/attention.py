@@ -261,7 +261,7 @@ def gqa_cache_attend(
         vview = cache.v.append(v[:, :, 0])
         k_pages, k_scale = kview.pages()
         v_pages, v_scale = vview.pages()
-        hkv = k_pages.shape[2]
+        hkv = k_pages.shape[1]
         qk = q[:, :, 0].reshape(b, hkv, groups, head_dim).astype(jnp.float32)
         out = paged_attention(
             qk, k_pages, v_pages, kview.pt, new_len,

@@ -361,10 +361,10 @@ class ServeEngine:
         spec = self.slot_cache.spec
         tails = []
         for j, meta in enumerate(spec.paged):
-            rest = self.pool["data"][j].shape[2:]
-            tail = rest[meta.lead:]
+            shape = self.pool["data"][j].shape       # [rows, *mid, block, D]
+            tail = (shape[1:-2] + shape[-1:])[meta.lead:]
             if len(tail) not in (1, 2):
-                return None  # no [block, H, D] kernel layout for this leaf
+                return None  # no [H, block, D] kernel layout for this leaf
             tails.append(tail)
         from repro.core.dispatch import MixerPlan, MixerShape
         from repro.core.policy import MixerPolicy, resolve_policy
@@ -444,7 +444,6 @@ class ServeEngine:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed.compat import shard_map
         from repro.serve.pool import PagedCacheView
 
         spec = self._view_spec
@@ -472,11 +471,11 @@ class ServeEngine:
                 logits = lax.all_gather(logits, name, axis=0, tiled=True)
             return tok, logits, out.pool
 
-        return shard_map(
+        return jax.shard_map(
             _body, mesh=mesh,
             in_specs=(P(), P(el), self._pool_specs, P(el), P(el), P()),
             out_specs=(P(), P(), self._pool_specs),
-            check_rep=False)  # no replication rule exists for pallas_call
+            check_vma=False)  # no replication rule exists for pallas_call
 
     def _next_key(self) -> jax.Array:
         """Per-sampling-call PRNG key: split exactly like the legacy host

@@ -12,8 +12,8 @@ Discovery, like the dense pool, is family-agnostic and allocation-free:
     a **dense per-slot pool**: they are already O(1) in capacity, which is
     FLARE's serving pitch — its whole state is a "dense leaf" here.
 
-Token-axis leaves are stored block-granular in ``[num_blocks+1, block,
-*rest]`` storage (``views.py`` layouts; the ``+1`` is the trash sink) and
+Token-axis leaves are stored block-granular in ``[num_blocks+1, *mid,
+block, D]`` storage (``views.py`` layouts; the ``+1`` is the trash sink) and
 share ONE page table per slot across every leaf and layer (vLLM-style: a
 logical token block maps to the same physical id in each leaf's storage).
 Pool capacity is therefore sized in **tokens** (``pool_tokens``), not
@@ -145,9 +145,10 @@ class PagedModelCache:
         rows = self.num_blocks + self.shards  # shards x (shard_blocks + 1)
         for meta, rest in zip(self.spec.paged, self._rest_shapes):
             sd = self.quant.storage_dtype(meta.dtype)
-            data.append(jnp.zeros((rows, self.block) + rest, sd))
-            scale.append(jnp.ones((rows, self.block) + rest[:-1],
-                                  jnp.float32) if self.quant.scaled else None)
+            mid = rest[:-1]
+            data.append(jnp.zeros((rows,) + mid + (self.block, rest[-1]), sd))
+            scale.append(jnp.ones((rows,) + mid + (self.block,), jnp.float32)
+                         if self.quant.scaled else None)
         return {"dense": dense, "data": tuple(data), "scale": tuple(scale)}
 
     def pool_pspecs(self, axes) -> dict:

@@ -1,11 +1,14 @@
 """Gather/scatter adapters between block storage and dense cache layout
 (DESIGN.md §4 "Paged pool").
 
-A paged leaf lives in **storage layout** ``[num_blocks+1, block, *rest]``
-(the ``+1`` is the trash sink block; ``rest`` = the leaf's shape minus its
-slot and token axes, original order preserved). These pure functions move
-tensors between that layout and the dense leaf layout the model decode
-steps consume:
+A paged leaf lives in **storage layout** ``[num_blocks+1, *mid, block, D]``
+(the ``+1`` is the trash sink block; ``(*mid, D)`` = the leaf's shape minus
+its slot and token axes, original order preserved) and its per-row scales
+in ``[num_blocks+1, *mid, block]``. The in-page token axis sits second to
+last, so one page of one head is a whole ``[block, D]`` tile: the layout
+the TPU kernel can fetch for any head count (Mosaic tiles the last two
+dims of a block). These pure functions move tensors between that layout
+and the dense leaf layout the model decode steps consume:
 
   - :func:`gather_leaf`    page table -> dense leaf (dequant on read)
   - :func:`scatter_blocks` prefill insert: a request's bucket, block-split
@@ -107,6 +110,17 @@ def from_pool_layout(x: jax.Array, sax: int, tax: int) -> jax.Array:
     return x.transpose(inv)
 
 
+def _page_rows(x: jax.Array, page: jax.Array, off: jax.Array,
+               feature: bool = True) -> tuple:
+    """Index of the token rows at (physical page, in-page offset) in
+    storage ``x``: ``[NB+1, *mid, block, D]`` when ``feature`` (payload),
+    ``[NB+1, *mid, block]`` otherwise (scales). ``x.at[idx]`` addresses
+    ``[*page.shape, *mid(, D)]`` — the row layout ``quantize`` produces."""
+    mid = x.ndim - 2 - int(feature)
+    return ((page,) + (slice(None),) * mid + (off,)
+            + ((slice(None),) if feature else ()))
+
+
 def _pad_tokens(x: jax.Array, to: int) -> jax.Array:
     pad = to - x.shape[1]
     if pad <= 0:
@@ -128,8 +142,9 @@ def gather_leaf(data: jax.Array, scale: Optional[jax.Array], pt: jax.Array,
     pt: [S, P] physical block ids (trash for unmapped — gathered garbage is
     behind the decode validity mask).
     """
-    raw = data[pt]                                   # [S, P, block, *rest]
-    sc = scale[pt] if scale is not None else None    # [S, P, block, *rest[:-1]]
+    raw = jnp.moveaxis(data[pt], -2, 2)              # [S, P, block, *rest]
+    sc = (jnp.moveaxis(scale[pt], -1, 2)             # [S, P, block, *rest[:-1]]
+          if scale is not None else None)
     x = dequantize(spec.quant, raw, sc, jnp.dtype(meta.dtype))
     s, p, blk = x.shape[:3]
     x = x.reshape((s, p * blk) + x.shape[3:])[:, :meta.view]
@@ -146,9 +161,10 @@ def scatter_blocks(data: jax.Array, scale: Optional[jax.Array],
     y = _pad_tokens(y, npages * spec.block)
     y = y.reshape((g, npages, spec.block) + y.shape[2:])
     q, sc = quantize(spec.quant, y)
-    data = data.at[block_ids].set(q.astype(data.dtype))
+    # in-page token axis (2) to its storage position
+    data = data.at[block_ids].set(jnp.moveaxis(q, 2, -2).astype(data.dtype))
     if scale is not None:
-        scale = scale.at[block_ids].set(sc)
+        scale = scale.at[block_ids].set(jnp.moveaxis(sc, 2, -1))
     return data, scale
 
 
@@ -178,9 +194,9 @@ def scatter_rows(data: jax.Array, scale: Optional[jax.Array],
     trash = data.shape[0] - 1
     valid = jnp.arange(s, dtype=jnp.int32)[None, :] < lengths[:, None]
     page = jnp.where(valid, page, trash)
-    data = data.at[page, off].set(q.astype(data.dtype))
+    data = data.at[_page_rows(data, page, off)].set(q.astype(data.dtype))
     if scale is not None:
-        scale = scale.at[page, off].set(sc)
+        scale = scale.at[_page_rows(scale, page, off, False)].set(sc)
     return data, scale
 
 
@@ -207,9 +223,9 @@ def scatter_token_at(data: jax.Array, scale: Optional[jax.Array],
     col = jnp.take_along_axis(y, jnp.broadcast_to(idx, (s, 1) + y.shape[2:]),
                               axis=1)[:, 0]                       # [S, *rest]
     q, sc = quantize(spec.quant, col)
-    data = data.at[page, off].set(q.astype(data.dtype))
+    data = data.at[_page_rows(data, page, off)].set(q.astype(data.dtype))
     if scale is not None:
-        scale = scale.at[page, off].set(sc)
+        scale = scale.at[_page_rows(scale, page, off, False)].set(sc)
     return data, scale
 
 
@@ -231,7 +247,7 @@ class PagedTokenView:
     """A paged cache leaf in **kernel page layout**, standing in for the
     dense leaf inside the model's cache pytree when ``PoolSpec.kernel``.
 
-    Children: storage ``data`` ``[*lead, NB+1, block, *tail]`` (lead axes —
+    Children: storage ``data`` ``[*lead, NB+1, *mid, block, D]`` (lead axes —
     e.g. a stacked-layer L — moved in front so ``lax.scan`` over layers
     slices them like any other cache leaf), optional per-row ``scale``,
     the shared page table ``pt`` [S, P] and the precomputed write target
@@ -262,22 +278,23 @@ class PagedTokenView:
         """Write the new token's row ``col`` [S, *tail] (quantized) at each
         slot's (page, offset); idle slots hit the trash sink."""
         q, sc = quantize(self.quant, col)
-        data = self.data.at[self.page, self.off].set(q.astype(self.data.dtype))
+        data = self.data.at[_page_rows(self.data, self.page, self.off)].set(
+            q.astype(self.data.dtype))
         scale = self.scale
         if scale is not None:
-            scale = scale.at[self.page, self.off].set(sc)
+            scale = scale.at[_page_rows(scale, self.page, self.off, False)].set(sc)
         return PagedTokenView(data, scale, self.pt, self.page, self.off,
                               self.meta, self.block, self.quant)
 
     def pages(self):
-        """(data, scale) shaped for the Pallas kernel: data [NB, block, H,
-        D] and scale [NB, block, H] — a featureless leaf (e.g. mla latent
+        """(data, scale) shaped for the Pallas kernel: data [NB, H, block,
+        D] and scale [NB, H, block] — a featureless leaf (e.g. mla latent
         rows, tail = (D,)) gets a singleton head axis."""
         data, scale = self.data, self.scale
         if data.ndim == 3:
-            data = data[:, :, None, :]
+            data = data[:, None]
             if scale is not None:
-                scale = scale[:, :, None]
+                scale = scale[:, None]
         return data, scale
 
 
@@ -370,7 +387,7 @@ class PagedCacheView:
             scale = self.pool["scale"][j]
             lead = meta.lead
             if lead:
-                src = tuple(range(2, 2 + lead))
+                src = tuple(range(1, 1 + lead))
                 dst = tuple(range(lead))
                 data = jnp.moveaxis(data, src, dst)
                 if scale is not None:
@@ -386,7 +403,7 @@ class PagedCacheView:
     def kernel_writeback(self, new_caches) -> "PagedCacheView":
         """Fold kernel-route caches back: paged leaves already hold the
         appended storage (``PagedTokenView.append`` wrote the row), so
-        they just move back to canonical ``[NB+1, block, *rest]`` layout;
+        they just move back to canonical storage layout;
         dense leaves are replaced wholesale."""
         spec = self.spec
         is_view = lambda x: isinstance(x, PagedTokenView)
@@ -403,7 +420,7 @@ class PagedCacheView:
             d, s = leaf.data, leaf.scale
             if lead:
                 src = tuple(range(lead))
-                dst = tuple(range(2, 2 + lead))
+                dst = tuple(range(1, 1 + lead))
                 d = jnp.moveaxis(d, src, dst)
                 if s is not None:
                     s = jnp.moveaxis(s, src, dst)

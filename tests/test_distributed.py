@@ -35,7 +35,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.flare import flare_mixer
 from repro.core.flare_sp import flare_mixer_seqparallel
 
-from repro.distributed.compat import make_mesh, shard_map
+from repro.distributed.sharding import make_mesh
+from jax import shard_map
 mesh = make_mesh((8,), ("seq",))
 key = jax.random.PRNGKey(0)
 H, M, N, D, B = 4, 16, 64, 8, 2
@@ -90,7 +91,7 @@ step = make_train_step(m.loss, tcfg, num_microbatches=2)
 p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
 # 4x2 mesh
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 p_sh = param_shardings(jax.eval_shape(lambda: params), mesh)
 o_sh = type(opt)(m=param_shardings(jax.eval_shape(lambda: opt.m), mesh),
@@ -118,7 +119,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.models.api import get_model
 from repro.distributed.sharding import param_shardings
 
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 for arch in ARCH_IDS:
     cfg = get_config(arch)
@@ -150,7 +151,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import compressed_mean
 
-from repro.distributed.compat import make_mesh, shard_map
+from repro.distributed.sharding import make_mesh
+from jax import shard_map
 
 mesh = make_mesh((8,), ("dp",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 128))

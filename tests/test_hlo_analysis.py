@@ -91,7 +91,7 @@ import jax, jax.numpy as jnp, sys
 sys.path.insert(0, "src")
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.analysis.hlo import analyze_hlo
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 mesh = make_mesh((4,), ("d",))
 w = jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)
 x = jax.ShapeDtypeStruct((4, 128), jnp.float32)

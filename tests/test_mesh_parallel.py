@@ -26,7 +26,7 @@ from repro.core.dispatch import (MixerShape, backends, eligible, get_backend,
 def test_packed_shard_matches_packed_fwd_bwd():
     run_in_cpu_mesh(r"""
 import numpy as np, jax, jax.numpy as jnp
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 from repro.kernels.flare_packed import flare_mixer_packed
 from repro.kernels.flare_packed_shard import flare_mixer_packed_shard
 
@@ -60,7 +60,7 @@ def test_packed_shard_1d_mesh_and_registry_route():
     # sequence-only sharding (no latent axis), driven through the registry
     run_in_cpu_mesh(r"""
 import numpy as np, jax, jax.numpy as jnp
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 from repro.core.dispatch import run_mixer, resolve, MixerShape
 from repro.kernels.flare_packed import flare_mixer_packed
 
@@ -100,7 +100,7 @@ import jax, numpy as np
 from repro.configs import get_smoke_config
 from repro.models.api import get_model
 from repro.serve.engine import ServeEngine
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 
 cfg = get_smoke_config("qwen2_1_5b")
 model = get_model(cfg, seq_len_hint=64)
@@ -137,7 +137,7 @@ SHAPE = MixerShape(batch=4, heads=4, tokens=64, latents=8, head_dim=8)
 
 
 def _probe_mesh():
-    from repro.distributed.compat import make_mesh
+    from repro.distributed.sharding import make_mesh
 
     return make_mesh((1, 1), ("data", "model"))
 

@@ -414,8 +414,8 @@ def test_paged_attention_kernel_matches_oracle():
     rng = np.random.default_rng(0)
     nb, block, h, d = 9, 8, 2, 16
     b, g, p = 3, 4, 4
-    k = jnp.asarray(rng.normal(size=(nb, block, h, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(nb, block, h, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(nb, h, block, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(nb, h, block, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(b, h, g, d)), jnp.float32)
     pt = jnp.asarray(rng.integers(0, nb, (b, p)), jnp.int32)  # incl. trash row
     lengths = jnp.asarray([0, 13, 32], jnp.int32)  # empty lane, partial page
@@ -431,8 +431,8 @@ def test_paged_attention_single_query_decode_shape():
     from repro.kernels.paged_attention import paged_attention, paged_attention_ref
 
     rng = np.random.default_rng(1)
-    k = jnp.asarray(rng.normal(size=(5, 4, 2, 8)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(5, 4, 2, 8)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(5, 2, 4, 8)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(5, 2, 4, 8)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(2, 2, 1, 8)), jnp.float32)
     pt = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     lengths = jnp.asarray([7, 3], jnp.int32)
