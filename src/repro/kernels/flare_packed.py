@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.obs import scope
+
 NEG_INF = -1e30
 LANE = 128
 # Scoped-VMEM cap for these launches. The backward sweep keeps ~10 live
@@ -229,6 +231,8 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
     mp = cfg.mp
     grid = (g, 2, n_blocks)
     kernel = functools.partial(_fused_fwd_kernel, cfg=cfg, n_blocks=n_blocks)
+    # ``name`` also opens jax.named_scope(name) around the launch, so the
+    # instruction and its op_name carry it
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -266,6 +270,7 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
         ],
         compiler_params=_compiler_params(),
         interpret=cfg.interpret,
+        name="flare_packed_fwd",
     )(q_p, k_p, v_p)
 
 
@@ -399,6 +404,7 @@ def _bwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den, y_p, dy_p):
         ],
         compiler_params=_compiler_params(),
         interpret=cfg.interpret,
+        name="flare_packed_bwd",
     )(q_p, k_p, v_p, z, mx, den, y_p, dy_p)
 
 
@@ -421,10 +427,11 @@ def _packed_core_fwd(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
 
 def _packed_core_bwd(cfg: _PackedCfg, gh: int, res, dy):
     q_p, k_p, v_p, z, mx, den, y = res
-    dq_g, dk, dv = _bwd_launch(cfg, gh, q_p, k_p, v_p, z, mx, den, y, dy)
-    # latent queries are shared across the batch: reduce the per-group dq
-    g, mp, wl = dq_g.shape
-    dq = dq_g.reshape(g // gh, gh, mp, wl).sum(axis=0).astype(q_p.dtype)
+    with scope("flare_packed_bwd"):
+        dq_g, dk, dv = _bwd_launch(cfg, gh, q_p, k_p, v_p, z, mx, den, y, dy)
+        # latent queries are shared across the batch: reduce the per-group dq
+        g, mp, wl = dq_g.shape
+        dq = dq_g.reshape(g // gh, gh, mp, wl).sum(axis=0).astype(q_p.dtype)
     return dq, dk, dv
 
 
@@ -494,12 +501,15 @@ def flare_mixer_packed(
     bn = min(block_n, _round_up(n, 16))
     np_ = _round_up(n, bn)
 
-    qp = _pack_heads(_pad_axis(_pad_axis(q.astype(k.dtype), 0, hp), 1, mp),
-                     gh, pack, wl)
-    kp = _pack_heads(_pad_axis(_pad_axis(k, 1, hp), 2, np_), gh, pack, wl)
-    vp = _pack_heads(_pad_axis(_pad_axis(v, 1, hp), 2, np_), gh, pack, wl)
-    kp = kp.reshape(b * gh, np_, wl)
-    vp = vp.reshape(b * gh, np_, wl)
+    # the wrapper's layout work (head packing, lane and token padding and
+    # their inverses) is named apart from the launches in XLA profiles
+    with scope("flare_packed.layout"):
+        qp = _pack_heads(_pad_axis(_pad_axis(q.astype(k.dtype), 0, hp), 1, mp),
+                         gh, pack, wl)
+        kp = _pack_heads(_pad_axis(_pad_axis(k, 1, hp), 2, np_), gh, pack, wl)
+        vp = _pack_heads(_pad_axis(_pad_axis(v, 1, hp), 2, np_), gh, pack, wl)
+        kp = kp.reshape(b * gh, np_, wl)
+        vp = vp.reshape(b * gh, np_, wl)
 
     cfg = _PackedCfg(
         pack=pack, mp=mp, d=d, block_n=bn,
@@ -508,5 +518,6 @@ def flare_mixer_packed(
         interpret=bool(interpret),
     )
     y = _packed_core(cfg, gh, qp, kp, vp)            # [B*Gh, Np, Wl]
-    y = _unpack_heads(y.reshape(b, gh, np_, wl), pack, d)
-    return y[:, :h, :n, :]
+    with scope("flare_packed.layout"):
+        y = _unpack_heads(y.reshape(b, gh, np_, wl), pack, d)
+        return y[:, :h, :n, :]

@@ -218,6 +218,7 @@ def paged_attention(
         out_shape=jax.ShapeDtypeStruct((bsz, h, g, d),
                                        out_dtype or v_pages.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, lengths, *operands)
 
 

@@ -8,13 +8,19 @@ profiler shims):
   - :mod:`repro.obs.trace` — request-lifecycle span tracing with
     Chrome-trace-event (Perfetto-loadable) export.
   - :func:`annotate` / :func:`scope` — the two XLA-profile correlation
-    shims. ``annotate(name)`` is a HOST-side ``jax.profiler.
-    TraceAnnotation``: wrap the dispatch of a compiled program (a prefill
+    shims, and :func:`phase`, which joins ``annotate`` to a tracer.
+    ``annotate(name)`` is a HOST-side ``jax.profiler.TraceAnnotation``:
+    wrap the dispatch of a compiled program (a prefill
     launch, the fused decode step, a train step) so the host row of a
     ``jax.profiler.trace`` capture carries the same names as the engine's
     span stream. ``scope(name)`` is ``jax.named_scope``: legal INSIDE
     traced code (it only tags jaxpr/HLO metadata, no runtime effect), so
     kernel launches and model phases show up named in XLA profiles.
+    ``phase(tracer, name)`` opens a host phase on both host streams at
+    once: ``annotate(name)`` on the profiler's host row and, when the
+    tracer is enabled, a complete span of the same name stamped inside it.
+    Both clocks are the wall clock (the profile's host events sit at its
+    ``profile_start_time`` plus their offset), so the two streams line up.
 
 The boundary rule (enforced by flarecheck OB001): clocks and registry
 mutation live at host boundaries only — never inside a jitted function, a
@@ -24,6 +30,7 @@ allowed inside traced code.
 from __future__ import annotations
 
 import contextlib
+import time
 
 from repro.obs.metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry,
@@ -37,7 +44,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "NULL_REGISTRY", "REGISTRY", "get_registry",
     "NULL_TRACER", "PHASES", "Span", "TID_ENGINE", "Tracer",
-    "annotate", "scope",
+    "annotate", "phase", "scope",
 ]
 
 
@@ -65,3 +72,20 @@ def scope(name: str):
         return jax.named_scope(name)
     except Exception:  # pragma: no cover — host-only tooling contexts
         return contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def phase(tracer, name: str, *, cat: str = "train", args: dict | None = None):
+    """One host phase on both host streams: ``annotate(name)`` and, when
+    ``tracer`` is enabled, ``tracer.complete(name, ...)`` with the start
+    and end stamped inside the annotation; ``args`` carries the identifier
+    the phases of one step share (``{"step": n}``). Host code only, like
+    :func:`annotate`; records through ``complete`` alone, so any object
+    with ``enabled`` and ``complete`` serves as the tracer."""
+    with annotate(name):
+        t0 = time.time()
+        try:
+            yield
+        finally:
+            if tracer.enabled:
+                tracer.complete(name, t0, time.time() - t0, cat=cat, args=args)

@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
 from repro.config import TrainConfig
 from repro.distributed.sharding import batch_spec, param_shardings
-from repro.obs import annotate
+from repro.obs import annotate, phase
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER
 from repro.optim.adamw import init_adamw
@@ -80,6 +80,7 @@ class Trainer:
         self.step = 0
         self.params = None
         self.opt_state = None
+        self._programs = {}
         self._build()
 
     # ------------------------------------------------------------ setup
@@ -137,6 +138,19 @@ class Trainer:
         except ValueError:  # non-main thread (tests)
             pass
 
+    def step_program(self, batch):
+        """The compiled train step for the trainer's state and ``batch``
+        (arrays or ``jax.ShapeDtypeStruct``s), compiled once per batch
+        shape: the program ``fit`` runs on such batches. ``as_text()``
+        gives each instruction with its ``op_name`` (the scopes that made
+        it), ``memory_analysis()`` the bytes one step holds."""
+        leaves, tree = jax.tree_util.tree_flatten(batch)
+        key = (tree, tuple((tuple(x.shape), str(x.dtype)) for x in leaves))
+        if key not in self._programs:
+            self._programs[key] = self._train_step.lower(
+                self.params, self.opt_state, batch).compile()
+        return self._programs[key]
+
     def _handle_term(self, signum, frame):  # noqa: ARG002
         log.warning("signal %s received: will checkpoint and stop", signum)
         self._stop = True
@@ -148,41 +162,50 @@ class Trainer:
         steps = steps or self.tcfg.steps
         history = []
         while self.step < steps and not self._stop:
-            t0 = time.time()
-            batch = batch_fn(self.step)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            t1 = time.time()  # host data feed done; device step begins
+            n = self.step
+            ids = {"step": n}
+            # the profiler's host row sees the iteration and its phases
+            # under ``train/...``; an enabled tracer gets each phase as a
+            # span of the same name, and ``train_step`` after the iteration
             with annotate("train/step"):
-                self.params, self.opt_state, metrics = self._train_step(
-                    self.params, self.opt_state, batch)
+                t0 = time.time()
+                with phase(self.tracer, "train/data", args=ids):
+                    batch = batch_fn(n)
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                t1 = time.time()  # host data feed done; device step begins
+                with phase(self.tracer, "train/dispatch", args=ids):
+                    self.params, self.opt_state, metrics = self._train_step(
+                        self.params, self.opt_state, batch)
                 # the float() sync blocks until the step has executed, so
                 # everything after t1 is device step + metric readback
-                metrics = {k: float(v) for k, v in metrics.items()}
-            now = time.time()
-            dt = now - t0
-            self._m_steps.inc()
-            self._m_data_s.observe(t1 - t0)
-            self._m_step_s.observe(now - t1)
+                with phase(self.tracer, "train/sync", args=ids):
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                now = time.time()
+                dt = now - t0
+                self._m_steps.inc()
+                self._m_data_s.observe(t1 - t0)
+                self._m_step_s.observe(now - t1)
+                self._watchdog(dt)
+                self.step += 1
+                metrics["step"] = self.step
+                metrics["time"] = dt
+                history.append(metrics)
+                if self.step % self.tcfg.log_every == 0:
+                    log.info("step %d loss %.4f gnorm %.3f lr %.2e (%.2fs)",
+                             self.step, metrics["loss"], metrics["grad_norm"],
+                             metrics["lr"], dt)
+                if self.step % self.tcfg.checkpoint_every == 0:
+                    with phase(self.tracer, "train/checkpoint", args=ids):
+                        self.ckpt.save(self.step, self.params)
+                    self._m_ckpts.inc()
+            # only once every annotation of the step has closed: a tracer
+            # may stop the profiler when it receives the step
             if self.tracer.enabled:
                 self.tracer.complete(
                     "train_step", t0, dt, cat="train",
-                    args={"step": self.step, "data_s": round(t1 - t0, 6),
+                    args={"step": n, "data_s": round(t1 - t0, 6),
                           "step_s": round(now - t1, 6),
                           "loss": metrics.get("loss")})
-            self._watchdog(dt)
-            self.step += 1
-            metrics["step"] = self.step
-            metrics["time"] = dt
-            history.append(metrics)
-            if self.step % self.tcfg.log_every == 0:
-                log.info("step %d loss %.4f gnorm %.3f lr %.2e (%.2fs)",
-                         self.step, metrics["loss"], metrics["grad_norm"],
-                         metrics["lr"], dt)
-            if self.step % self.tcfg.checkpoint_every == 0:
-                self.ckpt.save(self.step, self.params)
-                self._m_ckpts.inc()
-                self.tracer.instant("checkpoint", cat="train",
-                                    args={"step": self.step})
         # final (blocking) save — also the preemption path
         self.ckpt.save(self.step, self.params, blocking=True)
         return history
