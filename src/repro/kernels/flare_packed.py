@@ -36,6 +36,20 @@ lane-dimension segmentation.
 All padding (head count to a pack multiple, M to the sublane tile, N to the
 block boundary, lanes to 128) happens in plain-JAX wrapper code, so JAX
 autodiff composes the pack/unpack reshapes with the kernel's custom VJP.
+
+Steady-state sweep steps repeat no per-group setup. The block-diagonal
+latent queries (and, in the backward, Z), together with the f32
+block-diagonal indicator, are built in VMEM scratch on each group's first
+grid step and only read after that; the group axis is the grid's
+outermost, so every group, on whatever core runs it, starts there. The
+token-padding mask is built only on the last N block, the one block that
+can hold padding: both forms of a sweep step come from one body with a
+static ``masked`` flag (:func:`_sweep`), and the gauge
+``flare_packed.masked_block_share`` records the share of blocks that take
+the masked form. Neither changes a value the kernels compute. The shared
+helpers (``_bd_mask``, ``_expand_block_diag``, ``_scores``, ``_token_ok``,
+``_decode_weights``) keep their defaults for ``flare_packed_shard``, which
+still builds its invariants on every step.
 """
 from __future__ import annotations
 
@@ -47,6 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.obs import scope
+from repro.obs.metrics import REGISTRY
 
 NEG_INF = -1e30
 LANE = 128
@@ -127,13 +142,16 @@ def _compact_block_diag(cfg: _PackedCfg, x_bd: jax.Array) -> jax.Array:
     return out
 
 
-def _scores(cfg: _PackedCfg, qbd: jax.Array, k: jax.Array, n_idx) -> jax.Array:
+def _scores(cfg: _PackedCfg, qbd: jax.Array, k: jax.Array, n_idx,
+            *, token_mask: bool = True) -> jax.Array:
     """[S, bn] latent-major scores with token- and latent-padding masked to
-    NEG_INF (exactly the mask the forward statistics were built under)."""
+    NEG_INF (exactly the mask the forward statistics were built under).
+    ``token_mask=False`` leaves the token mask out, for a block that holds
+    no token padding."""
     s = jax.lax.dot_general(qbd, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     ok = None
-    if cfg.n_valid is not None:
+    if token_mask and cfg.n_valid is not None:
         cols = n_idx * cfg.block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         ok = cols < cfg.n_valid
     if cfg.m_valid is not None:
@@ -166,47 +184,73 @@ def _decode_weights(cfg: _PackedCfg, s: jax.Array) -> jax.Array:
     return parts[0] if cfg.pack == 1 else jnp.concatenate(parts, axis=0)
 
 
+def _sweep(cfg: _PackedCfg, n_idx, n_blocks: int, body) -> None:
+    """Run ``body(masked)`` for this grid step of an N sweep. Only the last
+    block can hold token padding, so ``masked`` is True there (and only when
+    N carries padding); every other block runs the body without the mask.
+    A masked body is always the last block's, whose index is static."""
+    if cfg.n_valid is None:
+        body(False)
+        return
+    last = n_idx == n_blocks - 1
+    pl.when(last)(lambda: body(True))
+    pl.when(jnp.logical_not(last))(lambda: body(False))
+
+
+def _note_masked_share(cfg: _PackedCfg, n_blocks: int) -> None:
+    """Gauge the share of each sweep's blocks that build the token mask."""
+    REGISTRY.gauge(
+        "flare_packed.masked_block_share",
+        "share of a packed sweep's N blocks that build the token-padding mask",
+    ).set(1.0 / n_blocks if cfg.n_valid is not None else 0.0)
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel: encode sweep (phase 0) then decode sweep (phase 1)
 # ---------------------------------------------------------------------------
 
 
 def _fused_fwd_kernel(q_ref, k_ref, v_ref, y_ref, z_ref, mx_ref, den_ref,
-                      mx_scr, den_scr, num_scr, zbd_scr, *,
+                      mx_scr, den_scr, num_scr, zbd_scr, qbd_scr, bd_scr, *,
                       cfg: _PackedCfg, n_blocks: int):
     phase = pl.program_id(1)
     n_idx = pl.program_id(2)
-    wl = q_ref.shape[-1]
-    bd = _bd_mask(cfg, wl)
-    qbd = _expand_block_diag(cfg, q_ref[0], bd)   # input dtype; fp32 scores
+    last = n_blocks - 1
 
     @pl.when(jnp.logical_and(phase == 0, n_idx == 0))
     def _init():
+        # per-group invariants, read by every later step of the group
+        bd = _bd_mask(cfg, q_ref.shape[-1])
+        bd_scr[...] = bd.astype(jnp.float32)
+        qbd_scr[...] = _expand_block_diag(cfg, q_ref[0], bd)  # input dtype
         mx_scr[...] = jnp.full_like(mx_scr, NEG_INF)
         den_scr[...] = jnp.zeros_like(den_scr)
         num_scr[...] = jnp.zeros_like(num_scr)
 
     @pl.when(phase == 0)
     def _encode():
-        k = k_ref[0]
-        v = v_ref[0]
-        s = _scores(cfg, qbd, k, n_idx)                  # [S, bn]
-        m_prev = mx_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        ok = _token_ok(cfg, s.shape, n_idx)
-        if ok is not None:
-            p = jnp.where(ok, p, 0.0)
-        den_scr[...] = den_scr[...] * alpha + jnp.sum(p, axis=-1)
-        num_scr[...] = num_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        mx_scr[...] = m_new
+        def step(masked):
+            k = k_ref[0]
+            v = v_ref[0]
+            s = _scores(cfg, qbd_scr[...], k, last, token_mask=masked)  # [S, bn]
+            m_prev = mx_scr[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, None])
+            if masked:
+                p = jnp.where(_token_ok(cfg, s.shape, last), p, 0.0)
+            den_scr[...] = den_scr[...] * alpha + jnp.sum(p, axis=-1)
+            num_scr[...] = num_scr[...] * alpha[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            mx_scr[...] = m_new
 
-        @pl.when(n_idx == n_blocks - 1)
+        _sweep(cfg, n_idx, n_blocks, step)
+
+        @pl.when(n_idx == last)
         def _finish_encode():
-            zbd = jnp.where(bd, num_scr[...] / den_scr[...][:, None], 0.0)
+            zbd = jnp.where(bd_scr[...] != 0.0,
+                            num_scr[...] / den_scr[...][:, None], 0.0)
             zbd_scr[...] = zbd
             z_ref[0] = _compact_block_diag(cfg, zbd)
             mx_ref[0, 0] = mx_scr[...]
@@ -214,13 +258,15 @@ def _fused_fwd_kernel(q_ref, k_ref, v_ref, y_ref, z_ref, mx_ref, den_ref,
 
     @pl.when(phase == 1)
     def _decode():
-        k = k_ref[0]
-        s = _scores(cfg, qbd, k, n_idx)                  # [S, bn]
-        w = _decode_weights(cfg, s)                      # [S, bn]
-        # y[n, c] = sum_s w[s, n] * Z_bd[s, c] — contraction over sublanes
-        y = jax.lax.dot_general(w, zbd_scr[...], (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        y_ref[0] = y.astype(y_ref.dtype)
+        def step(masked):
+            s = _scores(cfg, qbd_scr[...], k_ref[0], last, token_mask=masked)
+            w = _decode_weights(cfg, s)                      # [S, bn]
+            # y[n, c] = sum_s w[s, n] * Z_bd[s, c] — contraction over sublanes
+            y = jax.lax.dot_general(w, zbd_scr[...], (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            y_ref[0] = y.astype(y_ref.dtype)
+
+        _sweep(cfg, n_idx, n_blocks, step)
 
 
 def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
@@ -231,6 +277,7 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
     mp = cfg.mp
     grid = (g, 2, n_blocks)
     kernel = functools.partial(_fused_fwd_kernel, cfg=cfg, n_blocks=n_blocks)
+    _note_masked_share(cfg, n_blocks)
     # ``name`` also opens jax.named_scope(name) around the launch, so the
     # instruction and its op_name carry it
     return pl.pallas_call(
@@ -267,6 +314,8 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
             _vmem((s_rows, wl), jnp.float32),     # running numerator
             _vmem((s_rows, wl), jnp.float32),     # Z block-diagonal (lives
                                                   # across the phase switch)
+            _vmem((s_rows, wl), q_p.dtype),       # block-diagonal Q
+            _vmem((s_rows, wl), jnp.float32),     # block-diagonal indicator
         ],
         compiler_params=_compiler_params(),
         interpret=cfg.interpret,
@@ -281,17 +330,19 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
 
 def _fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref,
                       dq_ref, dk_ref, dv_ref,
-                      dz_scr, dqa_scr, de_scr, *,
+                      dz_scr, dqa_scr, de_scr, qbd_scr, zbd_scr, bd_scr, *,
                       cfg: _PackedCfg, n_blocks: int):
     phase = pl.program_id(1)
     n_idx = pl.program_id(2)
-    wl = q_ref.shape[-1]
-    bd = _bd_mask(cfg, wl)
-    qbd = _expand_block_diag(cfg, q_ref[0], bd)          # input dtype
-    zbd = _expand_block_diag(cfg, z_ref[0], bd)          # saved Z, fp32
+    last = n_blocks - 1
 
     @pl.when(jnp.logical_and(phase == 0, n_idx == 0))
     def _init():
+        # per-group invariants, read by every later step of the group
+        bd = _bd_mask(cfg, q_ref.shape[-1])
+        bd_scr[...] = bd.astype(jnp.float32)
+        qbd_scr[...] = _expand_block_diag(cfg, q_ref[0], bd)   # input dtype
+        zbd_scr[...] = _expand_block_diag(cfg, z_ref[0], bd)   # saved Z, fp32
         dz_scr[...] = jnp.zeros_like(dz_scr)
         dqa_scr[...] = jnp.zeros_like(dqa_scr)
         de_scr[...] = jnp.zeros_like(de_scr)
@@ -299,64 +350,74 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref
     @pl.when(phase == 0)
     def _sweep_dz():
         # dZ_p = sum_n W_p[n, :]^T dy_p[n, :]: recompute the decode weights
-        # from K (no [N, M] residual), accumulate with the block-diagonal
-        # mask so cross-head lanes never contaminate dZ.
-        k = k_ref[0]
-        dy = dy_ref[0].astype(jnp.float32)
-        s = _scores(cfg, qbd, k, n_idx)
-        w = _decode_weights(cfg, s)
-        dz_scr[...] = dz_scr[...] + jnp.where(bd, jax.lax.dot_general(
-            w, dy, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32), 0.0)
+        # from K (no [N, M] residual). Cross-head lanes accumulate too and
+        # are zeroed once, by the block-diagonal mask, when the sweep ends:
+        # the same values as masking every step's term.
+        def step(masked):
+            dy = dy_ref[0].astype(jnp.float32)
+            s = _scores(cfg, qbd_scr[...], k_ref[0], last, token_mask=masked)
+            w = _decode_weights(cfg, s)
+            dz_scr[...] = dz_scr[...] + jax.lax.dot_general(
+                w, dy, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-        @pl.when(n_idx == n_blocks - 1)
+        _sweep(cfg, n_idx, n_blocks, step)
+
+        @pl.when(n_idx == last)
         def _finish_dz():
+            dz = jnp.where(bd_scr[...] != 0.0, dz_scr[...], 0.0)
+            dz_scr[...] = dz
             # flash trick: rowsum(dA ∘ A) == rowsum(dZ ∘ Z) per latent row
-            de_scr[...] = jnp.sum(dz_scr[...] * zbd, axis=-1)
+            de_scr[...] = jnp.sum(dz * zbd_scr[...], axis=-1)
 
     @pl.when(phase == 1)
     def _sweep_grads():
-        k = k_ref[0]
-        v = v_ref[0].astype(jnp.float32)
-        y = y_ref[0].astype(jnp.float32)
-        dy = dy_ref[0].astype(jnp.float32)
-        s = _scores(cfg, qbd, k, n_idx)
-        # encode weights from saved stats (flash recomputation)
-        a = jnp.exp(s - mx_ref[0, 0][:, None]) / den_ref[0, 0][:, None]
-        ok = _token_ok(cfg, s.shape, n_idx)
-        if ok is not None:
-            a = jnp.where(ok, a, 0.0)
-        w = _decode_weights(cfg, s)
-        # decode softmax VJP (per token, per head segment):
-        #   dW[s, n]    = sum_c Z_bd[s, c] dy[n, c]
-        #   delta[s, n] = sum_{c in head(s)} dy[n, c] y[n, c]  (== dy·y per
-        #                 head — the decode flash trick), broadcast over the
-        #                 segment's rows by the block-diagonal indicator
-        dw = jax.lax.dot_general(zbd, dy, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        delta = jax.lax.dot_general(bd.astype(jnp.float32), dy * y,
-                                    (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        ds_dec = w * (dw - delta)
-        # encode softmax VJP: dA = dZ V^T, delta_enc = rowsum(dZ ∘ Z)
-        da = jax.lax.dot_general(dz_scr[...], v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds_enc = a * (da - de_scr[...][:, None])
-        ds = ds_enc + ds_dec                              # [S, bn]
-        dk_ref[0] = jax.lax.dot_general(
-            ds, qbd.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dk_ref.dtype)
-        dv_ref[0] = jax.lax.dot_general(
-            a, dz_scr[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-        dqa_scr[...] = dqa_scr[...] + jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        def step(masked):
+            k = k_ref[0]
+            v = v_ref[0].astype(jnp.float32)
+            y = y_ref[0].astype(jnp.float32)
+            dy = dy_ref[0].astype(jnp.float32)
+            qbd = qbd_scr[...]
+            zbd = zbd_scr[...]
+            s = _scores(cfg, qbd, k, last, token_mask=masked)
+            # encode weights from saved stats (flash recomputation)
+            a = jnp.exp(s - mx_ref[0, 0][:, None]) / den_ref[0, 0][:, None]
+            if masked:
+                a = jnp.where(_token_ok(cfg, s.shape, last), a, 0.0)
+            w = _decode_weights(cfg, s)
+            # decode softmax VJP (per token, per head segment):
+            #   dW[s, n]    = sum_c Z_bd[s, c] dy[n, c]
+            #   delta[s, n] = sum_{c in head(s)} dy[n, c] y[n, c]  (== dy·y per
+            #                 head — the decode flash trick), broadcast over the
+            #                 segment's rows by the block-diagonal indicator
+            dw = jax.lax.dot_general(zbd, dy, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            delta = jax.lax.dot_general(bd_scr[...], dy * y,
+                                        (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+            ds_dec = w * (dw - delta)
+            # encode softmax VJP: dA = dZ V^T, delta_enc = rowsum(dZ ∘ Z)
+            da = jax.lax.dot_general(dz_scr[...], v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds_enc = a * (da - de_scr[...][:, None])
+            ds = ds_enc + ds_dec                              # [S, bn]
+            dk_ref[0] = jax.lax.dot_general(
+                ds, qbd.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+            dv_ref[0] = jax.lax.dot_general(
+                a, dz_scr[...], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+            dqa_scr[...] = dqa_scr[...] + jax.lax.dot_general(
+                ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-        @pl.when(n_idx == n_blocks - 1)
+        _sweep(cfg, n_idx, n_blocks, step)
+
+        @pl.when(n_idx == last)
         def _finish_dq():
             dq_ref[0] = _compact_block_diag(
-                cfg, jnp.where(bd, dqa_scr[...], 0.0)).astype(dq_ref.dtype)
+                cfg, jnp.where(bd_scr[...] != 0.0, dqa_scr[...], 0.0)
+            ).astype(dq_ref.dtype)
 
 
 def _bwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den, y_p, dy_p):
@@ -367,6 +428,7 @@ def _bwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den, y_p, dy_p):
     mp = cfg.mp
     grid = (g, 2, n_blocks)
     kernel = functools.partial(_fused_bwd_kernel, cfg=cfg, n_blocks=n_blocks)
+    _note_masked_share(cfg, n_blocks)
     q_spec = pl.BlockSpec((1, mp, wl), lambda g_, p_, n_: (g_ % gh, 0, 0))
     # streamed [G, Np, Wl] tensors; the ``when`` factor pins the index to
     # block 0 in the phase that does not consume them
@@ -401,6 +463,9 @@ def _bwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den, y_p, dy_p):
             _vmem((s_rows, wl), jnp.float32),   # dZ accumulator
             _vmem((s_rows, wl), jnp.float32),   # dq accumulator
             _vmem((s_rows,), jnp.float32),      # delta_enc
+            _vmem((s_rows, wl), q_p.dtype),     # block-diagonal Q
+            _vmem((s_rows, wl), jnp.float32),   # block-diagonal Z
+            _vmem((s_rows, wl), jnp.float32),   # block-diagonal indicator
         ],
         compiler_params=_compiler_params(),
         interpret=cfg.interpret,

@@ -11,7 +11,9 @@ import pytest
 
 from repro.core import dispatch
 from repro.core.flare import flare_mixer
+from repro.core.policy import MixerPolicy
 from repro.kernels.flare_packed import flare_mixer_packed, heuristic_pack
+from repro.obs.metrics import REGISTRY
 
 KEY = jax.random.PRNGKey(7)
 
@@ -98,6 +100,131 @@ class TestGradParity:
         f = jax.jit(jax.grad(lambda q: jnp.sum(
             flare_mixer_packed(q, k, v, block_n=16) ** 2)))
         assert bool(jnp.isfinite(f(q)).all())
+
+
+# grids with several groups (B*Gh >= 3) and N blocks (>= 3), so that the
+# per-group scratch is rebuilt and the sweep runs first, steady and last
+# blocks; N padded to the block and not, every pack, and latent padding
+SWEEP_CASES = {
+    "pack1_Npad": dict(b=2, h=3, m=16, n=70, pack=1),
+    "pack1_Neven": dict(b=2, h=3, m=16, n=64, pack=1),
+    "pack2_Npad": dict(b=2, h=4, m=16, n=50, pack=2),
+    "pack2_Neven": dict(b=2, h=4, m=16, n=48, pack=2),
+    "pack4_Npad": dict(b=3, h=4, m=16, n=40, pack=4),
+    "pack4_Neven": dict(b=3, h=4, m=16, n=48, pack=4),
+    "pack2_Mpad_Npad": dict(b=2, h=3, m=12, n=45, pack=2),
+}
+
+
+class TestSweepInvariants:
+    """The packed launches build per-group invariants once per group and the
+    token-padding mask only on the last N block; results must not move."""
+
+    @pytest.mark.parametrize("case", SWEEP_CASES.values(), ids=SWEEP_CASES.keys())
+    def test_forward_and_grads_match_materialized(self, case):
+        case = dict(case)
+        pack = case.pop("pack")
+        q, k, v = _qkv(d=8, **case)
+        w = jax.random.normal(jax.random.fold_in(KEY, 13), v.shape)
+        ref_pol = MixerPolicy(backends=("materialized",))
+
+        def packed(q, k, v):
+            return flare_mixer_packed(q, k, v, pack=pack, block_n=16)
+
+        def ref(q, k, v):
+            return flare_mixer(q, k, v, policy=ref_pol)
+
+        np.testing.assert_allclose(np.asarray(packed(q, k, v)),
+                                   np.asarray(ref(q, k, v)),
+                                   atol=1e-5, rtol=1e-5)
+        gp = jax.grad(lambda *a: jnp.sum(w * packed(*a)), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(lambda *a: jnp.sum(w * ref(*a)), argnums=(0, 1, 2))(q, k, v)
+        for got, want in zip(gp, gr):
+            scale = np.abs(np.asarray(want)).max() + 1e-12
+            np.testing.assert_allclose(np.asarray(got) / scale,
+                                       np.asarray(want) / scale,
+                                       atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize("launch", ["flare_packed_fwd", "flare_packed_bwd"])
+    def test_steady_steps_build_no_masks(self, launch):
+        """Walk each launch's kernel jaxpr as one grid step would run it:
+        the steady state (a middle N block, either phase) executes no
+        integer div/rem and no 2-D iota; the group's first step and the
+        last block do (the group-init and last-block ``pl.when``s)."""
+        q, k, v = _qkv(h=4, m=16, n=70, d=8)         # 5 blocks of 16, padded
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flare_mixer_packed(q, k, v, pack=2, block_n=16)),
+            argnums=(0, 1, 2)))(q, k, v)
+        kernel = _pallas_kernel_jaxpr(jaxpr.jaxpr, launch)
+        for phase in (0, 1):
+            assert _mask_work(kernel, (1, phase, 2)) == [], (launch, phase)
+            assert _mask_work(kernel, (1, phase, 4)), (launch, phase)   # last block
+        assert any(p == "div" for p, _ in _mask_work(kernel, (1, 0, 0)))
+
+    @pytest.mark.parametrize("n, share", [(70, 1 / 5), (64, 0.0)],
+                             ids=["Npad", "Neven"])
+    def test_masked_block_share_gauge(self, n, share):
+        q, k, v = _qkv(h=2, m=16, n=n, d=8)
+        for fn in (lambda q, k, v: flare_mixer_packed(q, k, v, block_n=16),
+                   jax.grad(lambda q, k, v: jnp.sum(
+                       flare_mixer_packed(q, k, v, block_n=16)))):
+            REGISTRY.gauge("flare_packed.masked_block_share").set(-1.0)
+            jax.make_jaxpr(fn)(q, k, v)                # builds the launch(es)
+            assert REGISTRY.get("flare_packed.masked_block_share").value == share
+
+
+def _pallas_kernel_jaxpr(jaxpr, name):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and \
+                eqn.params["name"] == name:
+            return eqn.params["jaxpr"]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found = _pallas_kernel_jaxpr(sub, name)
+            if found is not None:
+                return found
+    return None
+
+
+def _mask_work(jaxpr, program_ids, env=None):
+    """(primitive, shape) of every integer div/rem and every 2-D iota that
+    one grid step at ``program_ids`` executes. Scalar integer and boolean
+    equations are evaluated, so each ``pl.when`` takes the branch that step
+    would; a branch whose index is not known counts as taken."""
+    from jax.extend.core import Literal
+
+    env = dict(env or {})
+    read = lambda v: v.val if isinstance(v, Literal) else env.get(v)
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        ins = [read(v) for v in eqn.invars]
+        if name == "program_id":
+            env[eqn.outvars[0]] = np.int32(program_ids[eqn.params["axis"]])
+        elif name == "cond":
+            branches = eqn.params["branches"]
+            taken = branches if ins[0] is None else [branches[int(ins[0])]]
+            for br in taken:
+                inner = {bv: x for bv, x in zip(br.jaxpr.invars, ins[1:])
+                         if x is not None}
+                found += _mask_work(br.jaxpr, program_ids, inner)
+        elif name in ("jit", "pjit", "closed_call"):
+            sub = eqn.params.get("jaxpr") or eqn.params["call_jaxpr"]
+            sub = getattr(sub, "jaxpr", sub)
+            inner = {sv: x for sv, x in zip(sub.invars, ins) if x is not None}
+            found += _mask_work(sub, program_ids, inner)
+        else:
+            out = eqn.outvars[0].aval if eqn.outvars else None
+            if name in ("div", "rem") and out.shape and \
+                    jnp.issubdtype(out.dtype, jnp.integer):
+                found.append((name, out.shape))
+            elif name == "iota" and len(out.shape) >= 2:
+                found.append((name, out.shape))
+            elif out is not None and out.shape == () and \
+                    all(x is not None for x in ins) and \
+                    not jnp.issubdtype(out.dtype, jnp.floating):
+                env[eqn.outvars[0]] = np.asarray(
+                    eqn.primitive.bind(*ins, **eqn.params))
+    return found
 
 
 class TestDispatch:
