@@ -22,6 +22,22 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling as DeepSeek-V2's ``rope_scaling`` gives it
+    (arXiv:2309.00071): frequencies interpolated by ``factor`` outside the
+    correction range that ``beta_fast``/``beta_slow`` set over
+    ``original_max_position_embeddings``; cos/sin scaled by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim) and the softmax
+    scale by mscale(factor, mscale_all_dim)**2."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class AttnConfig:
     kind: str = "gqa"  # gqa | mla | flare_stream | none
     num_heads: int = 8
@@ -32,6 +48,7 @@ class AttnConfig:
     sliding_window: Optional[int] = None  # tokens; None => full attention
     mrope_sections: Optional[Tuple[int, int, int]] = None  # qwen2-vl M-RoPE
     mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnConfig] = None  # MLA's rotary dims only
     # flare_stream mixer options
     flare_latents: int = 0
     flare_chunk: int = 256
@@ -52,10 +69,19 @@ class MoEConfig:
     num_shared: int = 0
     expert_ffn: int = 1408          # per-expert hidden size
     shared_ffn: int = 0             # hidden size of the shared expert(s)
-    capacity_factor: float = 1.25
     norm_topk_prob: bool = True     # renormalize gates over the selected k
     routed_scale: float = 1.0       # deepseek routed_scaling_factor
     first_dense_layers: int = 0     # leading layers that use a dense FFN
+    aux_alpha: float = 0.01         # weight of the sequence-wise balance loss
+    # the routed experts this layer holds: [expert_offset, expert_offset +
+    # experts_held) of num_experts (None: all) -- one rank's share under
+    # expert parallelism; the router still scores all num_experts
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None else self.experts_held
 
 
 @dataclass(frozen=True)

@@ -1,5 +1,6 @@
 """mixtral-8x7b [moe] — 32L d_model=4096 32H (GQA kv=8) expert_ffn=14336
-vocab=32000; 8 experts top-2 (softmax over the selected), sliding-window
+vocab=32000; 8 experts top-2 (gates renormalized over the selected: a
+softmax over their logits), sliding-window
 attention (4096) — which bounds the decode cache and makes long_500k
 runnable. [arXiv:2401.04088]
 """
@@ -20,7 +21,7 @@ def config() -> ModelConfig:
         ),
         moe=MoEConfig(
             num_experts=8, top_k=2, num_shared=0, expert_ffn=14336,
-            capacity_factor=1.25, norm_topk_prob=False,
+            norm_topk_prob=True,
         ),
         norm="rmsnorm",
         tie_embeddings=False,
@@ -39,8 +40,7 @@ def smoke_config() -> ModelConfig:
         vocab=128,
         attn=AttnConfig(kind="gqa", num_heads=4, num_kv_heads=2, head_dim=16,
                         sliding_window=8),
-        moe=MoEConfig(num_experts=4, top_k=2, expert_ffn=96, capacity_factor=2.0,
-                      norm_topk_prob=False),
+        moe=MoEConfig(num_experts=4, top_k=2, expert_ffn=96, norm_topk_prob=True),
         norm="rmsnorm",
         remat="none",
     )

@@ -60,6 +60,10 @@ class Model:
     # resolved mixer plans ({"train": ..., "infer": ...}) for FLARE-mixing
     # families; empty for pure-attention/SSM families
     plans: Mapping[str, Any] = field(default_factory=dict)
+    # (params, batch) -> (loss, {name: scalar}): the loss with per-step
+    # device counters (MoE routing) that a train step returns beside its
+    # metrics; None where the model counts nothing
+    loss_counters: Optional[Callable[..., Any]] = None
 
 
 def make_prefill_into(prefill, init_caches):
@@ -188,6 +192,10 @@ def get_model(cfg: ModelConfig, *, policy=None, mesh=None,
         # prefix-cache suffix path: only where the cache is stable,
         # position-addressable history (unwindowed gqa/mla over token ids)
         lm_suffix = None
+        loss_counters = None
+        if cfg.moe is not None:
+            loss_counters = lambda p, b: t.lm_loss_and_counters(
+                p, b, cfg, mixer_plan=train_plan)
         if (cfg.attn.kind in ("gqa", "mla") and cfg.attn.sliding_window is None
                 and not cfg.inputs_are_embeddings):
             lm_suffix = lambda p, b, c: t.lm_prefill_suffix(p, b, c, cfg)
@@ -204,6 +212,7 @@ def get_model(cfg: ModelConfig, *, policy=None, mesh=None,
             prefill_into=make_prefill_into(lm_prefill, lm_caches),
             prefill_suffix=lm_suffix,
             plans=plans,
+            loss_counters=loss_counters,
         )
     if fam in ("encdec", "audio"):
         from repro.models import transformer as t

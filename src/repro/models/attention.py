@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import AttnConfig
-from repro.models.rope import apply_rope, mrope_angles, rope_angles
+from repro.models.rope import apply_rope, mrope_angles, rope_angles, yarn_mscale
 from repro.nn.modules import dense, init_dense, init_rmsnorm, rmsnorm
 
 # ---------------------------------------------------------------------------
@@ -92,7 +92,9 @@ def _chunked_attention(q, k, v, *, scale, causal, window, q_offset, chunk):
     """Flash-style online-softmax over query blocks, expressed in XLA.
 
     Scans query blocks; each block computes scores against the full K/V but
-    the [chunk, Skv] score tile is the only large intermediate alive.
+    the [chunk, Skv] score tile is the only large intermediate alive. Each
+    block is rematerialized in the backward pass, so it too holds one
+    block's scores rather than all of them.
     """
     b, h, sq, d = q.shape
     skv = k.shape[-2]
@@ -120,7 +122,7 @@ def _chunked_attention(q, k, v, *, scale, causal, window, q_offset, chunk):
         den = jnp.sum(e, axis=-1, keepdims=True).astype(v.dtype)
         return None, num / jnp.maximum(den, 1e-30)
 
-    _, out = jax.lax.scan(body, None, (jnp.arange(nblocks), qb))
+    _, out = jax.lax.scan(jax.checkpoint(body), None, (jnp.arange(nblocks), qb))
     dv = v.shape[-1]  # may differ from the q/k head dim (e.g. MLA)
     out = out.transpose(1, 2, 0, 3, 4).reshape(b, h, nblocks * chunk, dv)
     return out[:, :, :sq]
@@ -439,6 +441,30 @@ def init_mla(key, cfg: AttnConfig, d_model: int, *, param_dtype=jnp.float32) -> 
     return params
 
 
+def _mla_rope(x, positions, cfg: AttnConfig):
+    """Rotate MLA's rotary dims, with YaRN frequencies and cos/sin scale
+    where the config has ``rope_scaling``."""
+    yarn = cfg.rope_scaling
+    out = apply_rope(x, rope_angles(positions, cfg.mla.qk_rope_head_dim,
+                                    cfg.rope_theta, yarn))
+    if yarn is not None:
+        mscale = (yarn_mscale(yarn.factor, yarn.mscale)
+                  / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if mscale != 1.0:
+            out = (out.astype(jnp.float32) * mscale).astype(x.dtype)
+    return out
+
+
+def mla_softmax_scale(cfg: AttnConfig) -> float:
+    """(qk_nope + qk_rope)^-1/2, times mscale(factor, mscale_all_dim)^2
+    under YaRN (0.11472 for DeepSeek-V2-Lite)."""
+    m, yarn = cfg.mla, cfg.rope_scaling
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_queries(params, x, cfg: AttnConfig, positions):
     m = cfg.mla
     h = cfg.num_heads
@@ -448,9 +474,7 @@ def _mla_queries(params, x, cfg: AttnConfig, positions):
         q = dense(params["w_q"], x)
     q = _heads(q, h)  # [B, H, S, qk_nope + qk_rope]
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    ang = rope_angles(positions, m.qk_rope_head_dim, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, ang)
-    return q_nope, q_rope
+    return q_nope, _mla_rope(q_rope, positions, cfg)
 
 
 def mla_forward(
@@ -469,15 +493,14 @@ def mla_forward(
     h = cfg.num_heads
     q_nope, q_rope = _mla_queries(params, x, cfg, positions)
     c_kv = rmsnorm(params["kv_norm"], dense(params["w_dkv"], x))  # [B, S, r]
-    k_rope = dense(params["w_kr"], x)  # [B, S, rope_dim] shared single head
-    ang = rope_angles(positions, m.qk_rope_head_dim, cfg.rope_theta)
-    k_rope = apply_rope(k_rope, ang)
+    k_rope = _mla_rope(dense(params["w_kr"], x), positions, cfg)  # [B, S, rope] one head
     k_nope = _heads(dense(params["w_uk"], c_kv), h)  # [B, H, S, nope]
     v = _heads(dense(params["w_uv"], c_kv), h)       # [B, H, S, v_dim]
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, None], k_nope.shape[:3] + (m.qk_rope_head_dim,))], axis=-1)
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    out = attn_sdpa(q, k, v, scale=scale, causal=causal, window=None, impl=impl)
+    with jax.named_scope("mla.attention"):
+        out = attn_sdpa(q, k, v, scale=mla_softmax_scale(cfg), causal=causal,
+                        window=None, impl=impl)
     y = dense(params["w_o"], _unheads(out))
     if return_kv:
         return y, (c_kv, k_rope)
@@ -507,14 +530,12 @@ def mla_decode(
     q_abs = jnp.einsum("bhsd,rhd->bhsr", q_nope, w_uk)
 
     c_new = rmsnorm(params["kv_norm"], dense(params["w_dkv"], x))  # [B, 1, r]
-    kr_new = dense(params["w_kr"], x)
-    ang = rope_angles(positions, m.qk_rope_head_dim, cfg.rope_theta)
-    kr_new = apply_rope(kr_new, ang)
+    kr_new = _mla_rope(dense(params["w_kr"], x), positions, cfg)
 
     b = x.shape[0]
     length = _per_slot(cache.length, b)
     new_len = length + 1
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    scale = mla_softmax_scale(cfg)
 
     from repro.serve.pool.views import PagedTokenView
 
@@ -586,9 +607,7 @@ def mla_extend(
     q_nope, q_rope = _mla_queries(params, x, cfg, positions)  # [B,H,S,*]
 
     c_new = rmsnorm(params["kv_norm"], dense(params["w_dkv"], x))  # [B, S, r]
-    kr_new = dense(params["w_kr"], x)
-    ang = rope_angles(positions, m.qk_rope_head_dim, cfg.rope_theta)
-    kr_new = apply_rope(kr_new, ang)
+    kr_new = _mla_rope(dense(params["w_kr"], x), positions, cfg)
 
     s = x.shape[1]
     cap = cache.c_kv.shape[1]
@@ -603,8 +622,7 @@ def mla_extend(
         [k_nope, jnp.broadcast_to(kr_all[:, None],
                                   k_nope.shape[:3] + (m.qk_rope_head_dim,))],
         axis=-1)
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    scores = jnp.einsum("bhsd,bhtd->bhst", q, k).astype(jnp.float32) * scale
+    scores = jnp.einsum("bhsd,bhtd->bhst", q, k).astype(jnp.float32) * mla_softmax_scale(cfg)
     ti = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, cap), 3)
     qi = offsets[:, None, None, None] + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, s, 1), 2)

@@ -1,6 +1,8 @@
-"""Rotary position embeddings: standard RoPE and Qwen2-VL M-RoPE."""
+"""Rotary position embeddings: standard RoPE, YaRN-scaled RoPE (DeepSeek-V2's
+``rope_scaling``) and Qwen2-VL M-RoPE."""
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -12,9 +14,44 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> jax.Array:
-    """positions [..., S] -> angles [..., S, head_dim//2] (fp32)."""
-    inv = rope_frequencies(head_dim, theta)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 * mscale * ln(factor) + 1`` (1 when the
+    context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(head_dim: int, theta: float, yarn) -> Tuple[int, int]:
+    """The rotary dims between which YaRN blends interpolated and original
+    frequencies: dims that turn more than ``beta_fast`` times over the
+    original context keep theirs, fewer than ``beta_slow`` are divided by
+    the factor. (10, 23) for DeepSeek-V2's 64 rotary dims."""
+    def dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = math.floor(dim(yarn.beta_fast))
+    high = math.ceil(dim(yarn.beta_slow))
+    return max(low, 0), min(high, head_dim - 1)
+
+
+def yarn_frequencies(head_dim: int, theta: float, yarn) -> jax.Array:
+    """YaRN inverse frequencies [head_dim // 2]: ``freq / factor`` past the
+    correction range, ``freq`` before it, a linear ramp between."""
+    extra = rope_frequencies(head_dim, theta)
+    inter = extra / yarn.factor
+    low, high = yarn_correction_range(head_dim, theta, yarn)
+    high = high + 0.001 if low == high else high
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low),
+                    0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def rope_angles(positions: jax.Array, head_dim: int, theta: float,
+                yarn=None) -> jax.Array:
+    """positions [..., S] -> angles [..., S, head_dim//2] (fp32); YaRN
+    frequencies when ``yarn`` (a ``YarnConfig``) is given."""
+    inv = (rope_frequencies(head_dim, theta) if yarn is None
+           else yarn_frequencies(head_dim, theta, yarn))
     return positions.astype(jnp.float32)[..., None] * inv
 
 

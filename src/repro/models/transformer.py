@@ -39,7 +39,7 @@ from repro.models.attention import (
     prefill_kv_cache,
     prefill_mla_cache,
 )
-from repro.models.moe import init_moe, moe_ffn
+from repro.models.moe import MoEStats, init_moe, moe_ffn
 from repro.models.rope import text_mrope_positions, text_positions
 from repro.nn.modules import (
     dense,
@@ -219,12 +219,13 @@ def _flare_stream_mix(layer, x, cfg: ModelConfig, *, plan=None):
 def decoder_layer_forward(layer, x, cfg: ModelConfig, *, positions, moe_cfg=None,
                           dense_ffn: bool = False, impl: str = "auto",
                           mixer_plan=None):
-    """One pre-norm block. Returns (x, aux_loss). ``impl`` is the SDPA
-    vocabulary ("auto" | "xla" | "chunked" | "pallas") for the gqa/mla
+    """One pre-norm block. Returns (x, MoEStats; zeros without routed
+    experts). ``impl`` is the SDPA vocabulary ("auto" | "xla" |
+    "chunked" | "pallas") for the gqa/mla
     attention paths; ``mixer_plan`` is the resolved FLARE MixerPlan for
     flare_stream layers — the two dispatch vocabularies are no longer
     conflated into one threaded kwarg."""
-    aux = jnp.zeros((), jnp.float32)
+    stats = MoEStats.zero()
     xin = _norm_apply(cfg, layer["norm1"], x)
     if cfg.attn.kind == "gqa":
         a = gqa_forward(layer["attn"], xin, cfg.attn, positions=positions, causal=True, impl=impl)
@@ -235,10 +236,10 @@ def decoder_layer_forward(layer, x, cfg: ModelConfig, *, positions, moe_cfg=None
     x = x + a
     xin = _norm_apply(cfg, layer["norm2"], x)
     if cfg.moe is not None and not dense_ffn:
-        m, aux = moe_ffn(layer["mlp"], xin, cfg.moe)
+        m, stats = moe_ffn(layer["mlp"], xin, cfg.moe)
     else:
         m = swiglu(layer["mlp"], xin)
-    return x + m, aux
+    return x + m, stats
 
 
 def init_lm(key, cfg: ModelConfig) -> dict:
@@ -276,53 +277,70 @@ def _embed_inputs(params, batch, cfg: ModelConfig):
     return x, positions
 
 
-def lm_forward(params, batch, cfg: ModelConfig, *, impl: str = "auto",
-               mixer_plan=None):
-    """Full-sequence forward -> (logits fp32 [B,S,V], aux_loss)."""
+def _lm_logits(params, batch, cfg: ModelConfig, *, impl: str, mixer_plan):
+    """Full-sequence forward -> (logits fp32 [B,S,V], MoEStats over the
+    layers)."""
     x, positions = _embed_inputs(params, batch, cfg)
 
-    def body(carry, layer):
-        x, aux = carry
-        x, a = decoder_layer_forward(layer, x, cfg, positions=positions, impl=impl,
-                                     mixer_plan=mixer_plan)
-        return (x, aux + a), None
-
-    aux0 = jnp.zeros((), jnp.float32)
-    if "dense_layers" in params:
-        def dense_body(carry, layer):
-            x, aux = carry
+    def body_for(dense_ffn):
+        def body(carry, layer):
+            x, st = carry
             x, a = decoder_layer_forward(layer, x, cfg, positions=positions,
-                                         dense_ffn=True, impl=impl,
+                                         dense_ffn=dense_ffn, impl=impl,
                                          mixer_plan=mixer_plan)
-            return (x, aux + a), None
+            return (x, st.merge(a)), None
+        return body
 
-        (x, aux0), _ = jax.lax.scan(_remat(dense_body, cfg.remat), (x, aux0), params["dense_layers"])
-    (x, aux), _ = jax.lax.scan(_remat(body, cfg.remat), (x, aux0), params["layers"])
+    st = MoEStats.zero()
+    if "dense_layers" in params:
+        (x, st), _ = jax.lax.scan(_remat(body_for(True), cfg.remat), (x, st),
+                                  params["dense_layers"])
+    (x, st), _ = jax.lax.scan(_remat(body_for(False), cfg.remat), (x, st), params["layers"])
     x = _norm_apply(cfg, params["final_norm"], x)
     if cfg.tie_embeddings:
         logits = x @ params["embed"]["table"].astype(x.dtype).T
     else:
         logits = dense(params["lm_head"], x)
     logits = mask_padded_logits(logits.astype(jnp.float32), cfg.vocab)
-    return logits, aux
+    return logits, st
 
 
-def lm_loss(params, batch, cfg: ModelConfig, *, impl: str = "auto",
-            mixer_plan=None):
-    """Next-token cross-entropy (labels = batch['labels'])."""
+def lm_forward(params, batch, cfg: ModelConfig, *, impl: str = "auto",
+               mixer_plan=None):
+    """Full-sequence forward -> (logits fp32 [B,S,V], aux_loss)."""
+    logits, st = _lm_logits(params, batch, cfg, impl=impl, mixer_plan=mixer_plan)
+    return logits, st.aux
+
+
+def lm_loss_and_counters(params, batch, cfg: ModelConfig, *, impl: str = "auto",
+                         mixer_plan=None):
+    """Next-token cross-entropy (labels = batch['labels']) plus the MoE
+    balance loss times ``aux_alpha``, and the step's routing counters:
+    ``moe.rows_held`` (token-slots routed to held experts, summed over the
+    layers) and ``moe.load_max_over_mean`` (worst layer); none without
+    routed experts."""
     from repro.core.policy import mixer_policy
 
     # the loss is the differentiated entry point: under a build-time plan the
     # grad contract was checked at resolve; for bare calls the policy scope
     # restricts ambient resolution to grad-capable mixers
     with mixer_policy(requires_grad=True):
-        logits, aux = lm_forward(params, batch, cfg, impl=impl,
-                                 mixer_plan=mixer_plan)
+        logits, st = _lm_logits(params, batch, cfg, impl=impl, mixer_plan=mixer_plan)
     labels = batch["labels"]
     logz = jax.scipy.special.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     nll = jnp.mean(logz - gold)
-    return nll + 0.01 * aux
+    if cfg.moe is None:
+        return nll, {}
+    return nll + cfg.moe.aux_alpha * st.aux, {
+        "moe.rows_held": st.rows_held,
+        "moe.load_max_over_mean": st.load_max_over_mean}
+
+
+def lm_loss(params, batch, cfg: ModelConfig, *, impl: str = "auto",
+            mixer_plan=None):
+    """:func:`lm_loss_and_counters` without the counters."""
+    return lm_loss_and_counters(params, batch, cfg, impl=impl, mixer_plan=mixer_plan)[0]
 
 
 # ------------------------------- serving ----------------------------------

@@ -18,15 +18,23 @@ from repro.optim.adamw import AdamWState, adamw_update
 from repro.optim.schedule import onecycle_schedule
 
 
-def make_train_step(loss_fn: Callable, tcfg: TrainConfig, *, num_microbatches: int = 1):
-    """loss_fn(params, microbatch) -> scalar. Returns train_step(params, opt, batch)."""
+def make_train_step(loss_fn: Callable, tcfg: TrainConfig, *, num_microbatches: int = 1,
+                    counters: bool = False):
+    """loss_fn(params, microbatch) -> scalar, or with ``counters`` ->
+    (scalar, {name: scalar}): device counters the step returns among its
+    metrics (averaged over microbatches). Returns train_step(params, opt,
+    batch)."""
 
     def grads_of(params, batch):
-        return jax.value_and_grad(loss_fn)(params, batch)
+        if counters:
+            (loss, cnt), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+            return loss, cnt, grads
+        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        return loss, {}, grads
 
     def train_step(params, opt_state: AdamWState, batch):
         if num_microbatches == 1:
-            loss, grads = grads_of(params, batch)
+            loss, cnt, grads = grads_of(params, batch)
         else:
             mbs = jax.tree.map(
                 lambda x: constrain_dim_to_batch_axes(
@@ -37,15 +45,16 @@ def make_train_step(loss_fn: Callable, tcfg: TrainConfig, *, num_microbatches: i
 
             def body(carry, mb):
                 gsum, lsum = carry
-                loss, grads = grads_of(params, mb)
+                loss, cnt, grads = grads_of(params, mb)
                 gsum = jax.tree.map(lambda a, g: a + g.astype(jnp.float32), gsum, grads)
-                return (gsum, lsum + loss), None
+                return (gsum, lsum + loss), cnt
 
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (gsum, lsum), _ = jax.lax.scan(body, (zeros, jnp.zeros((), jnp.float32)), mbs)
+            (gsum, lsum), cnt = jax.lax.scan(body, (zeros, jnp.zeros((), jnp.float32)), mbs)
             inv = 1.0 / num_microbatches
             grads = jax.tree.map(lambda g: g * inv, gsum)
             loss = lsum * inv
+            cnt = {k: jnp.mean(v) for k, v in cnt.items()}
 
         lr = onecycle_schedule(
             opt_state.step, total_steps=tcfg.steps, peak_lr=tcfg.learning_rate,
@@ -56,7 +65,7 @@ def make_train_step(loss_fn: Callable, tcfg: TrainConfig, *, num_microbatches: i
             lr=lr, weight_decay=tcfg.weight_decay, beta1=tcfg.beta1,
             beta2=tcfg.beta2, eps=tcfg.eps, grad_clip=tcfg.grad_clip,
         )
-        metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
+        metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr, **cnt}
         return params, opt_state, metrics
 
     return train_step

@@ -121,12 +121,26 @@ class Trainer:
         # available via save_full_state)
         self.opt_state = self.opt_state._replace(step=jnp.asarray(self.step, jnp.int32))
 
-        train_step = make_train_step(self.model.loss, self.tcfg,
-                                     num_microbatches=self.num_microbatches)
+        # a model that counts (MoE routing) returns its counters with the
+        # step's metrics, read back at the same ``train/sync``
+        counted = getattr(self.model, "loss_counters", None)
+        train_step = make_train_step(counted or self.model.loss, self.tcfg,
+                                     num_microbatches=self.num_microbatches,
+                                     counters=counted is not None)
         if self.mesh is not None:
+            # layers that split work over the mesh (the MoE layer's
+            # shard_map) find it as the ambient abstract mesh
+            meshed, abstract = train_step, self.mesh.abstract_mesh
+
+            def train_step(*args):
+                with jax.sharding.use_abstract_mesh(abstract):
+                    return meshed(*args)
+
             self._train_step = jax.jit(
                 train_step,
                 in_shardings=(self._p_sh, None, self._batch_sh),
+                # the next step takes the params as this one lays them out
+                out_shardings=(self._p_sh, None, None),
                 donate_argnums=(0, 1),
             )
         else:
