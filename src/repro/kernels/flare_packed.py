@@ -38,9 +38,9 @@ block boundary, lanes to 128) happens in plain-JAX wrapper code, so JAX
 autodiff composes the pack/unpack reshapes with the kernel's custom VJP.
 
 Steady-state sweep steps repeat no per-group setup. The block-diagonal
-latent queries (and, in the backward, Z), together with the f32
-block-diagonal indicator, are built in VMEM scratch on each group's first
-grid step and only read after that; the group axis is the grid's
+latent queries (and, in the backward, Z and the f32 block-diagonal
+indicator) are built in VMEM scratch on each group's first grid step and
+only read after that; the group axis is the grid's
 outermost, so every group, on whatever core runs it, starts there. The
 token-padding mask is built only on the last N block, the one block that
 can hold padding: both forms of a sweep step come from one body with a
@@ -50,6 +50,22 @@ the masked form. Neither changes a value the kernels compute. The shared
 helpers (``_bd_mask``, ``_expand_block_diag``, ``_scores``, ``_token_ok``,
 ``_decode_weights``) keep their defaults for ``flare_packed_shard``, which
 still builds its invariants on every step.
+
+Per-latent-row softmax statistics (the encode sweep's running max and
+denominator; the backward's saved max, denominator and ``delta_enc``) live
+in [S, 128] f32 VMEM scratch with each row's value replicated across the
+lanes, as flash-attention kernels keep m and l. A reduction over the token
+axis keeps that axis, and a tile reads a statistic as whole 128-lane copies
+(:func:`_lanes`), so a sweep step never holds a rank-1 [S] value: Mosaic
+lays those along lanes, and each step would permute them to and from the
+tile's sublanes. The statistics take their compact [G, 1, S] residual form
+only once per group, by a transpose, when the encode sweep ends; the
+backward widens them back, the same way, in its group's first step. The
+forward gives back the VMEM they take: its numerator accumulator turns into
+Z's block-diagonal scratch in place when the encode sweep ends, and it
+builds the block-diagonal mask there, once per group, instead of keeping
+it, so every (pack, block_n) the autotuner offers that fit before still
+fits ``VMEM_LIMIT``.
 """
 from __future__ import annotations
 
@@ -184,6 +200,15 @@ def _decode_weights(cfg: _PackedCfg, s: jax.Array) -> jax.Array:
     return parts[0] if cfg.pack == 1 else jnp.concatenate(parts, axis=0)
 
 
+def _lanes(x: jax.Array, width: int) -> jax.Array:
+    """[S, 128] lane-replicated row statistic -> [S, width], every lane
+    holding its row's value: whole copies of the 128 lanes, sliced when
+    ``width`` is not a multiple of 128 (a tile of 208 or of 16 tokens)."""
+    reps = -(-width // LANE)
+    x = x if reps == 1 else jnp.tile(x, (1, reps))
+    return x if width == reps * LANE else x[:, :width]
+
+
 def _sweep(cfg: _PackedCfg, n_idx, n_blocks: int, body) -> None:
     """Run ``body(masked)`` for this grid step of an N sweep. Only the last
     block can hold token padding, so ``masked`` is True there (and only when
@@ -211,7 +236,7 @@ def _note_masked_share(cfg: _PackedCfg, n_blocks: int) -> None:
 
 
 def _fused_fwd_kernel(q_ref, k_ref, v_ref, y_ref, z_ref, mx_ref, den_ref,
-                      mx_scr, den_scr, num_scr, zbd_scr, qbd_scr, bd_scr, *,
+                      mx_scr, den_scr, zbd_scr, qbd_scr, *,
                       cfg: _PackedCfg, n_blocks: int):
     phase = pl.program_id(1)
     n_idx = pl.program_id(2)
@@ -221,11 +246,10 @@ def _fused_fwd_kernel(q_ref, k_ref, v_ref, y_ref, z_ref, mx_ref, den_ref,
     def _init():
         # per-group invariants, read by every later step of the group
         bd = _bd_mask(cfg, q_ref.shape[-1])
-        bd_scr[...] = bd.astype(jnp.float32)
         qbd_scr[...] = _expand_block_diag(cfg, q_ref[0], bd)  # input dtype
         mx_scr[...] = jnp.full_like(mx_scr, NEG_INF)
         den_scr[...] = jnp.zeros_like(den_scr)
-        num_scr[...] = jnp.zeros_like(num_scr)
+        zbd_scr[...] = jnp.zeros_like(zbd_scr)
 
     @pl.when(phase == 0)
     def _encode():
@@ -233,28 +257,30 @@ def _fused_fwd_kernel(q_ref, k_ref, v_ref, y_ref, z_ref, mx_ref, den_ref,
             k = k_ref[0]
             v = v_ref[0]
             s = _scores(cfg, qbd_scr[...], k, last, token_mask=masked)  # [S, bn]
-            m_prev = mx_scr[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            m_prev = mx_scr[...]                                       # [S, 128]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, None])
+            p = jnp.exp(s - _lanes(m_new, s.shape[1]))
             if masked:
                 p = jnp.where(_token_ok(cfg, s.shape, last), p, 0.0)
-            den_scr[...] = den_scr[...] * alpha + jnp.sum(p, axis=-1)
-            num_scr[...] = num_scr[...] * alpha[:, None] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            den_scr[...] = den_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            zbd_scr[...] = zbd_scr[...] * _lanes(alpha, zbd_scr.shape[1]) + \
+                jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
             mx_scr[...] = m_new
 
         _sweep(cfg, n_idx, n_blocks, step)
 
         @pl.when(n_idx == last)
         def _finish_encode():
-            zbd = jnp.where(bd_scr[...] != 0.0,
-                            num_scr[...] / den_scr[...][:, None], 0.0)
+            # the numerator turns into Z_bd in place
+            wl = zbd_scr.shape[1]
+            zbd = jnp.where(_bd_mask(cfg, wl),
+                            zbd_scr[...] / _lanes(den_scr[...], wl), 0.0)
             zbd_scr[...] = zbd
             z_ref[0] = _compact_block_diag(cfg, zbd)
-            mx_ref[0, 0] = mx_scr[...]
-            den_ref[0, 0] = den_scr[...]
+            mx_ref[0] = mx_scr[...].T[0:1, :]
+            den_ref[0] = den_scr[...].T[0:1, :]
 
     @pl.when(phase == 1)
     def _decode():
@@ -309,13 +335,12 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
             jax.ShapeDtypeStruct((g, 1, s_rows), jnp.float32),   # encode den
         ],
         scratch_shapes=[
-            _vmem((s_rows,), jnp.float32),        # running max
-            _vmem((s_rows,), jnp.float32),        # running denominator
-            _vmem((s_rows, wl), jnp.float32),     # running numerator
-            _vmem((s_rows, wl), jnp.float32),     # Z block-diagonal (lives
+            _vmem((s_rows, LANE), jnp.float32),   # running max (lane-replicated)
+            _vmem((s_rows, LANE), jnp.float32),   # running denominator (same)
+            _vmem((s_rows, wl), jnp.float32),     # running numerator, then
+                                                  # Z block-diagonal (lives
                                                   # across the phase switch)
             _vmem((s_rows, wl), q_p.dtype),       # block-diagonal Q
-            _vmem((s_rows, wl), jnp.float32),     # block-diagonal indicator
         ],
         compiler_params=_compiler_params(),
         interpret=cfg.interpret,
@@ -330,7 +355,8 @@ def _fwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p):
 
 def _fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref,
                       dq_ref, dk_ref, dv_ref,
-                      dz_scr, dqa_scr, de_scr, qbd_scr, zbd_scr, bd_scr, *,
+                      dz_scr, dqa_scr, de_scr, mx_scr, den_scr, qbd_scr, zbd_scr,
+                      bd_scr, *,
                       cfg: _PackedCfg, n_blocks: int):
     phase = pl.program_id(1)
     n_idx = pl.program_id(2)
@@ -343,6 +369,9 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref
         bd_scr[...] = bd.astype(jnp.float32)
         qbd_scr[...] = _expand_block_diag(cfg, q_ref[0], bd)   # input dtype
         zbd_scr[...] = _expand_block_diag(cfg, z_ref[0], bd)   # saved Z, fp32
+        # the saved row statistics, lane-replicated for the grads sweep
+        mx_scr[...] = jnp.broadcast_to(mx_ref[0], (LANE, mx_scr.shape[0])).T
+        den_scr[...] = jnp.broadcast_to(den_ref[0], (LANE, den_scr.shape[0])).T
         dz_scr[...] = jnp.zeros_like(dz_scr)
         dqa_scr[...] = jnp.zeros_like(dqa_scr)
         de_scr[...] = jnp.zeros_like(de_scr)
@@ -368,7 +397,8 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref
             dz = jnp.where(bd_scr[...] != 0.0, dz_scr[...], 0.0)
             dz_scr[...] = dz
             # flash trick: rowsum(dA ∘ A) == rowsum(dZ ∘ Z) per latent row
-            de_scr[...] = jnp.sum(dz * zbd_scr[...], axis=-1)
+            de_scr[...] = jnp.broadcast_to(
+                jnp.sum(dz * zbd_scr[...], axis=-1, keepdims=True), de_scr.shape)
 
     @pl.when(phase == 1)
     def _sweep_grads():
@@ -381,7 +411,8 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref
             zbd = zbd_scr[...]
             s = _scores(cfg, qbd, k, last, token_mask=masked)
             # encode weights from saved stats (flash recomputation)
-            a = jnp.exp(s - mx_ref[0, 0][:, None]) / den_ref[0, 0][:, None]
+            bn = s.shape[1]
+            a = jnp.exp(s - _lanes(mx_scr[...], bn)) / _lanes(den_scr[...], bn)
             if masked:
                 a = jnp.where(_token_ok(cfg, s.shape, last), a, 0.0)
             w = _decode_weights(cfg, s)
@@ -399,7 +430,7 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, mx_ref, den_ref, y_ref, dy_ref
             # encode softmax VJP: dA = dZ V^T, delta_enc = rowsum(dZ ∘ Z)
             da = jax.lax.dot_general(dz_scr[...], v, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-            ds_enc = a * (da - de_scr[...][:, None])
+            ds_enc = a * (da - _lanes(de_scr[...], bn))
             ds = ds_enc + ds_dec                              # [S, bn]
             dk_ref[0] = jax.lax.dot_general(
                 ds, qbd.astype(jnp.float32), (((0,), (0,)), ((), ())),
@@ -462,7 +493,9 @@ def _bwd_launch(cfg: _PackedCfg, gh: int, q_p, k_p, v_p, z, mx, den, y_p, dy_p):
         scratch_shapes=[
             _vmem((s_rows, wl), jnp.float32),   # dZ accumulator
             _vmem((s_rows, wl), jnp.float32),   # dq accumulator
-            _vmem((s_rows,), jnp.float32),      # delta_enc
+            _vmem((s_rows, LANE), jnp.float32), # delta_enc (lane-replicated)
+            _vmem((s_rows, LANE), jnp.float32), # encode max (lane-replicated)
+            _vmem((s_rows, LANE), jnp.float32), # encode den (lane-replicated)
             _vmem((s_rows, wl), q_p.dtype),     # block-diagonal Q
             _vmem((s_rows, wl), jnp.float32),   # block-diagonal Z
             _vmem((s_rows, wl), jnp.float32),   # block-diagonal indicator

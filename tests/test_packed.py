@@ -113,6 +113,10 @@ SWEEP_CASES = {
     "pack4_Npad": dict(b=3, h=4, m=16, n=40, pack=4),
     "pack4_Neven": dict(b=3, h=4, m=16, n=48, pack=4),
     "pack2_Mpad_Npad": dict(b=2, h=3, m=12, n=45, pack=2),
+    # token tiles that the lane-replicated row statistics are widened to:
+    # 208 lanes (block_n 256 over N = 200, not a multiple of 128) and 128
+    "pack2_bn208": dict(b=2, h=2, m=16, n=200, pack=2, block_n=256),
+    "pack2_bn128": dict(b=2, h=2, m=16, n=300, pack=2, block_n=128),
 }
 
 
@@ -124,12 +128,13 @@ class TestSweepInvariants:
     def test_forward_and_grads_match_materialized(self, case):
         case = dict(case)
         pack = case.pop("pack")
+        block_n = case.pop("block_n", 16)
         q, k, v = _qkv(d=8, **case)
         w = jax.random.normal(jax.random.fold_in(KEY, 13), v.shape)
         ref_pol = MixerPolicy(backends=("materialized",))
 
         def packed(q, k, v):
-            return flare_mixer_packed(q, k, v, pack=pack, block_n=16)
+            return flare_mixer_packed(q, k, v, pack=pack, block_n=block_n)
 
         def ref(q, k, v):
             return flare_mixer(q, k, v, policy=ref_pol)
@@ -161,6 +166,91 @@ class TestSweepInvariants:
             assert _mask_work(kernel, (1, phase, 4)), (launch, phase)   # last block
         assert any(p == "div" for p, _ in _mask_work(kernel, (1, 0, 0)))
 
+    @pytest.mark.parametrize("case", SWEEP_CASES.values(), ids=SWEEP_CASES.keys())
+    def test_saved_row_statistics(self, case):
+        """The forward's saved ``mx`` and ``den`` (compacted once per group
+        from the sweep's lane-replicated statistics) are the row max and
+        the row sum of exp over the padded score matrix."""
+        from repro.kernels import flare_packed as fp
+
+        case = dict(case)
+        pack = case.pop("pack")
+        block_n = case.pop("block_n", 16)
+        q, k, v = _qkv(d=8, **case)
+        b, h, n, d = k.shape
+        m = q.shape[1]
+        gh = -(-h // pack)
+        mp = fp._round_up(m, 16)
+        wl = fp._round_up(pack * d, fp.LANE)
+        bn = min(block_n, fp._round_up(n, 16))
+        np_ = fp._round_up(n, bn)
+        q_pad = fp._pad_axis(fp._pad_axis(q, 0, gh * pack), 1, mp)
+        k_pad = fp._pad_axis(fp._pad_axis(k, 1, gh * pack), 2, np_)
+        v_pad = fp._pad_axis(fp._pad_axis(v, 1, gh * pack), 2, np_)
+        cfg = fp._PackedCfg(pack=pack, mp=mp, d=d, block_n=bn,
+                            n_valid=n if n < np_ else None,
+                            m_valid=m if m < mp else None, interpret=True)
+        _, (*_, mx, den, _) = fp._packed_core_fwd(
+            cfg, gh, fp._pack_heads(q_pad, gh, pack, wl),
+            fp._pack_heads(k_pad, gh, pack, wl).reshape(b * gh, np_, wl),
+            fp._pack_heads(v_pad, gh, pack, wl).reshape(b * gh, np_, wl))
+
+        s = jnp.einsum("hid,bhjd->bhij", q_pad, k_pad,
+                       precision=jax.lax.Precision.HIGHEST)
+        ok = (jnp.arange(mp) < m)[:, None] & (jnp.arange(np_) < n)[None, :]
+        s = jnp.where(ok, s, fp.NEG_INF)
+        want_mx = jnp.max(s, axis=-1)                          # [B, Hp, Mp]
+        want_den = jnp.sum(jnp.where(jnp.arange(np_) < n,
+                                     jnp.exp(s - want_mx[..., None]), 0.0),
+                           axis=-1)
+        as_saved = lambda x: x.reshape(b * gh, 1, pack * mp)  # head g*pack+p
+        np.testing.assert_allclose(np.asarray(mx), np.asarray(as_saved(want_mx)),
+                                   rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(den),
+                                   np.asarray(as_saved(want_den)), rtol=1e-6)
+
+    @pytest.mark.parametrize("launch, phase",
+                             [("flare_packed_fwd", 0), ("flare_packed_bwd", 1)],
+                             ids=["encode", "grads"])
+    def test_steady_steps_keep_row_statistics_lane_replicated(self, launch, phase):
+        """A steady step (a middle N block) of the forward's encode sweep
+        and of the backward's grads sweep holds its per-latent-row
+        statistics as [S, 128] lanes: every reduction over the token axis
+        keeps its axis, no rank-1 [S] value is broadcast across a tile, and
+        no other rank-1 [S] value (such as a read of [S] scratch) exists."""
+        q, k, v = _qkv(h=4, m=16, n=70, d=8)         # 5 blocks of 16, padded
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flare_mixer_packed(q, k, v, pack=2, block_n=16)),
+            argnums=(0, 1, 2)))(q, k, v)
+        kernel = _pallas_kernel_jaxpr(jaxpr.jaxpr, launch)
+        rows, bn = 2 * 16, 16                          # S = pack * Mp
+        reductions, dropped, broadcasts, other = 0, [], [], []
+        for body, eqn in _step_eqns(kernel, (1, phase, 2)):
+            name = eqn.primitive.name
+            if name == "broadcast_in_dim" and \
+                    eqn.invars[0].aval.shape == (rows,) and \
+                    eqn.params["shape"] == (rows, bn):
+                broadcasts.append(eqn)
+            for out in eqn.outvars:
+                if getattr(out.aval, "shape", None) != (rows,):
+                    continue
+                if name in ("reduce_max", "reduce_sum") and \
+                        eqn.params["axes"] == (1,):
+                    reductions += 1
+                    users = [e for e in body.eqns
+                             if any(x is out for x in e.invars)]
+                    if not users or any(
+                            u.primitive.name != "broadcast_in_dim" or
+                            u.params["shape"] != (rows, 1) for u in users) or \
+                            any(x is out for x in body.outvars):
+                        dropped.append(eqn)
+                else:
+                    other.append(eqn)
+        assert reductions == (2 if phase == 0 else 0)  # encode: max and sum
+        assert dropped == [], dropped
+        assert broadcasts == [], broadcasts
+        assert other == [], other
+
     @pytest.mark.parametrize("n, share", [(70, 1 / 5), (64, 0.0)],
                              ids=["Npad", "Neven"])
     def test_masked_block_share_gauge(self, n, share):
@@ -185,19 +275,20 @@ def _pallas_kernel_jaxpr(jaxpr, name):
     return None
 
 
-def _mask_work(jaxpr, program_ids, env=None):
-    """(primitive, shape) of every integer div/rem and every 2-D iota that
-    one grid step at ``program_ids`` executes. Scalar integer and boolean
-    equations are evaluated, so each ``pl.when`` takes the branch that step
-    would; a branch whose index is not known counts as taken."""
+def _step_eqns(jaxpr, program_ids, env=None):
+    """(jaxpr, equation) for every equation that one grid step at
+    ``program_ids`` executes, with the jaxpr that holds it. Scalar integer
+    and boolean equations are evaluated, so each ``pl.when`` takes the
+    branch that step would; a branch whose index is not known counts as
+    taken."""
     from jax.extend.core import Literal
 
     env = dict(env or {})
     read = lambda v: v.val if isinstance(v, Literal) else env.get(v)
-    found = []
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         ins = [read(v) for v in eqn.invars]
+        yield jaxpr, eqn
         if name == "program_id":
             env[eqn.outvars[0]] = np.int32(program_ids[eqn.params["axis"]])
         elif name == "cond":
@@ -206,24 +297,30 @@ def _mask_work(jaxpr, program_ids, env=None):
             for br in taken:
                 inner = {bv: x for bv, x in zip(br.jaxpr.invars, ins[1:])
                          if x is not None}
-                found += _mask_work(br.jaxpr, program_ids, inner)
+                yield from _step_eqns(br.jaxpr, program_ids, inner)
         elif name in ("jit", "pjit", "closed_call"):
             sub = eqn.params.get("jaxpr") or eqn.params["call_jaxpr"]
             sub = getattr(sub, "jaxpr", sub)
             inner = {sv: x for sv, x in zip(sub.invars, ins) if x is not None}
-            found += _mask_work(sub, program_ids, inner)
-        else:
-            out = eqn.outvars[0].aval if eqn.outvars else None
-            if name in ("div", "rem") and out.shape and \
-                    jnp.issubdtype(out.dtype, jnp.integer):
-                found.append((name, out.shape))
-            elif name == "iota" and len(out.shape) >= 2:
-                found.append((name, out.shape))
-            elif out is not None and out.shape == () and \
-                    all(x is not None for x in ins) and \
-                    not jnp.issubdtype(out.dtype, jnp.floating):
-                env[eqn.outvars[0]] = np.asarray(
-                    eqn.primitive.bind(*ins, **eqn.params))
+            yield from _step_eqns(sub, program_ids, inner)
+        elif eqn.outvars and eqn.outvars[0].aval.shape == () and \
+                all(x is not None for x in ins) and \
+                not jnp.issubdtype(eqn.outvars[0].aval.dtype, jnp.floating):
+            env[eqn.outvars[0]] = np.asarray(eqn.primitive.bind(*ins, **eqn.params))
+
+
+def _mask_work(jaxpr, program_ids):
+    """(primitive, shape) of every integer div/rem and every 2-D iota that
+    one grid step at ``program_ids`` executes (:func:`_step_eqns`)."""
+    found = []
+    for _, eqn in _step_eqns(jaxpr, program_ids):
+        name = eqn.primitive.name
+        out = eqn.outvars[0].aval if eqn.outvars else None
+        if name in ("div", "rem") and out.shape and \
+                jnp.issubdtype(out.dtype, jnp.integer):
+            found.append((name, out.shape))
+        elif name == "iota" and len(out.shape) >= 2:
+            found.append((name, out.shape))
     return found
 
 

@@ -6,7 +6,9 @@ or VMEM limits: these compiles can. Each test compiles with
 attached, at the published widths of the model that uses the kernel:
 
   - ``flare_mixer_packed`` forward and gradient at ``flare_pde`` widths
-    (8 heads, M=2048, D=8) on a batch of 8 x 40,000 points;
+    (8 heads, M=2048, D=8) on a batch of 8 x 40,000 points, at a head pack
+    of 8 (16 heads, M=256: 2,048 packed latent rows), and over 200 points
+    (a 208-token tile, not a multiple of 128 lanes);
   - ``paged_attention`` at ``qwen2_1_5b`` widths (2 KV heads, 6 query heads
     per KV head, D=128, 16-token pages) over bf16 and int8 pages;
   - ``flare_mixer_packed_shard`` forward and gradient on a 2x2 mesh.
@@ -27,6 +29,8 @@ HBM_BYTES = 16 * 1024**3  # one v5e chip
 
 # flare_pde mixer widths (configs/flare_pde.py) at the pde_40k shape
 PDE = dict(B=8, H=8, M=2048, D=8, N=40000)
+# the packed kernels at a heuristic pack > 1 and at a ragged token tile
+PACKED = {"pack8": dict(PDE, H=16, M=256), "tile208": dict(PDE, N=200)}
 # qwen2_1_5b decode read (configs/qwen2_1_5b.py): 8 slots x 2048 capacity
 # over a 16,384-token pool of 16-token pages (+ the trash page)
 QWEN = dict(B=8, H=2, G=6, D=128, block=16, NB=1025, P=128)
@@ -78,6 +82,18 @@ def test_flare_packed_compiles_at_flare_pde_widths(one_chip, mode):
     s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     q = s((PDE["H"], PDE["M"], PDE["D"]))
     k = s((PDE["B"], PDE["H"], PDE["N"], PDE["D"]))
+    f = lambda q, k, v: flare_mixer_packed(q, k, v, interpret=False)
+    _compile(f if mode == "forward" else _grad(f), q, k, k)
+
+
+@pytest.mark.parametrize("shape", PACKED.values(), ids=PACKED.keys())
+@pytest.mark.parametrize("mode", ["forward", "grad"])
+def test_flare_packed_compiles_at_pack8_and_ragged_tile(one_chip, shape, mode):
+    from repro.kernels.flare_packed import flare_mixer_packed
+
+    s = lambda dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    q = s((shape["H"], shape["M"], shape["D"]))
+    k = s((shape["B"], shape["H"], shape["N"], shape["D"]))
     f = lambda q, k, v: flare_mixer_packed(q, k, v, interpret=False)
     _compile(f if mode == "forward" else _grad(f), q, k, k)
 
