@@ -1,8 +1,8 @@
 """Figure 8 (CPU-scaled): single-layer execution time — vanilla
 self-attention vs Transolver physics attention vs FLARE across N, plus a
-per-mixer-backend sweep (sdpa vs the two-launch pallas kernels vs the
-packed-head fused kernels) over the paper's small-D and a large-D config,
-so the perf trajectory (BENCH_<tag>.json) tracks every backend per commit.
+per-mixer-backend sweep (sdpa vs the packed-head fused kernels) over the
+paper's small-D and a large-D config, so the perf trajectory
+(BENCH_<tag>.json) tracks every backend per commit.
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ NS = (512, 1024, 2048, 4096)
 
 # per-backend FLARE layer times: D=8 (paper's tiny-head regime, where the
 # packed backend recovers lane utilization) and D=64
-BACKEND_IMPLS = ("sdpa", "pallas", "packed")
+BACKEND_IMPLS = ("sdpa", "packed")
 BACKEND_CONFIGS = {8: dict(dim=32, heads=4), 64: dict(dim=256, heads=4)}
 BACKEND_N = 512
 

@@ -81,7 +81,7 @@ echo "== fast tier (pytest -m 'not slow', allocator sanitizer on) =="
 REPRO_SANITIZE=1 python -m pytest -x -q -m "not slow"
 
 echo "== interpret-mode kernel-parity smoke =="
-# quick standalone guard: the fused kernels (packed + classic) against the
+# quick standalone guard: the fused kernels (packed + flash) against the
 # jnp oracles, exactly what a kernel regression would break first
 python -m pytest -x -q tests/test_kernels.py tests/test_packed.py \
     -k "sweep or oracles or matches"

@@ -4,8 +4,8 @@
 Each module registers one or more :class:`~repro.core.dispatch.MixerBackend`
 entries with capability metadata (causal/bidirectional contract, sharding
 requirements, device kinds, dtype constraints), a ``plan`` builder and a
-``run`` callable. New backends (GPU pallas, ring-attention encode, ...) plug
-in here — no call site changes needed.
+``run`` callable. New backends (a GPU kernel, ring-attention encode, ...)
+plug in here — no call site changes needed.
 """
 from repro.backends import (  # noqa: F401  (import for registration side effect)
     causal,
@@ -13,10 +13,9 @@ from repro.backends import (  # noqa: F401  (import for registration side effect
     packed,
     packed_shard,
     paged,
-    pallas,
     sdpa,
     seqparallel,
 )
 
 __all__ = ["autotune", "causal", "materialized", "packed", "packed_shard",
-           "paged", "pallas", "sdpa", "seqparallel"]
+           "paged", "sdpa", "seqparallel"]

@@ -5,9 +5,8 @@ central lesson), but the best choice depends on the problem shape, dtype and
 device generation — none of which a hardcoded default can know. This module:
 
   * proposes candidates for a :class:`~repro.core.dispatch.MixerShape` per
-    parameter *kind* — ``"tiles"`` is the classic ``(block_m, block_n)``
-    search for the two-launch kernels, ``"packed"`` additionally searches the
-    packed-head backend's head-pack factor alongside its N tile,
+    parameter *kind* — ``"packed"`` searches the packed-head backends'
+    head-pack factor alongside their N tile,
   * times them with a caller-supplied runner (so this module stays free of
     eager kernel imports; the pack heuristic is lazily imported), and
   * memoizes the winner in an on-disk JSON cache keyed by
@@ -101,25 +100,24 @@ def _base_key(shape: MixerShape, dtype, device: str, kind: str,
               mesh: Optional[tuple] = None) -> str:
     import jax.numpy as jnp
 
-    base = (f"{device}|{jnp.dtype(dtype).name}|N{shape.tokens}|M{shape.latents}"
-            f"|D{shape.head_dim}|H{shape.heads}")
+    base = (f"{kind}|{device}|{jnp.dtype(dtype).name}|N{shape.tokens}"
+            f"|M{shape.latents}|D{shape.head_dim}|H{shape.heads}")
     if mesh:
         # shard-shape component: a tile winner for a per-shard slice is not
         # evidence about the single-device problem (or another mesh shape) —
         # sharded entries get their own key space, unsharded keys are
         # byte-identical to the historical format so old caches keep hitting
         base = f"{base}|mesh{'x'.join(str(int(s)) for s in mesh)}"
-    # the historical "tiles" keys carry no kind prefix — existing caches stay valid
-    return base if kind == "tiles" else f"{kind}|{base}"
+    return base
 
 
-def cache_key(shape: MixerShape, dtype, device: str, kind: str = "tiles",
+def cache_key(shape: MixerShape, dtype, device: str, kind: str,
               mesh: Optional[tuple] = None) -> str:
     """The (runtime-versioned) key new winners are stored under."""
     return f"{_base_key(shape, dtype, device, kind, mesh)}|{runtime_version()}"
 
 
-def legacy_cache_key(shape: MixerShape, dtype, device: str, kind: str = "tiles",
+def legacy_cache_key(shape: MixerShape, dtype, device: str, kind: str,
                      mesh: Optional[tuple] = None) -> str:
     """Pre-versioning key format — still read as a fallback hit so caches
     written by earlier releases keep paying off until re-tuned."""
@@ -174,24 +172,8 @@ def _pow2s(lo: int, hi: int) -> list:
 
 # param names per kind — doubles as entry validation for cache hits
 _KIND_PARAMS = {
-    "tiles": ("block_m", "block_n"),
     "packed": ("block_n", "pack"),
 }
-
-
-def tile_candidates(shape: MixerShape) -> list:
-    """MXU-friendly (block_m, block_n) pairs clipped to the problem shape."""
-    n, m = shape.tokens, shape.latents
-    bms = [b for b in _pow2s(128, 512) if b <= max(128, m)] or [128]
-    bns = [b for b in _pow2s(256, 2048) if b <= max(256, n)] or [256]
-    return [{"block_m": bm, "block_n": bn} for bm in bms for bn in bns]
-
-
-def default_tiles(shape: MixerShape) -> dict:
-    """Heuristic fallback when no timed entry exists: the paper-bench
-    defaults, clipped so small problems still launch a single tile."""
-    return {"block_m": min(128, max(8, shape.latents)),
-            "block_n": min(512, max(128, shape.tokens))}
 
 
 def packed_candidates(shape: MixerShape) -> list:
@@ -212,8 +194,8 @@ def default_packed(shape: MixerShape) -> dict:
             "pack": heuristic_pack(shape.heads, shape.latents, shape.head_dim)}
 
 
-_CANDIDATES = {"tiles": tile_candidates, "packed": packed_candidates}
-_DEFAULTS = {"tiles": default_tiles, "packed": default_packed}
+_CANDIDATES = {"packed": packed_candidates}
+_DEFAULTS = {"packed": default_packed}
 
 
 # ---------------------------------------------------------------------------
@@ -222,9 +204,9 @@ _DEFAULTS = {"tiles": default_tiles, "packed": default_packed}
 
 
 def measure_tiles(shape: MixerShape, dtype, device: str,
-                  runner: Callable[[dict], float],
+                  runner: Callable[[dict], float], *, kind: str,
                   candidates: Optional[Iterable[dict]] = None,
-                  kind: str = "tiles", mesh: Optional[tuple] = None) -> dict:
+                  mesh: Optional[tuple] = None) -> dict:
     """Time each candidate with ``runner(params) -> seconds`` and cache the
     winner. Returns the winning param dict (also annotated with timings)."""
     cands = list(candidates) if candidates is not None else _CANDIDATES[kind](shape)
@@ -247,7 +229,7 @@ def measure_tiles(shape: MixerShape, dtype, device: str,
     return best
 
 
-def best_params(shape: MixerShape, dtype, device: str, *, kind: str = "tiles",
+def best_params(shape: MixerShape, dtype, device: str, *, kind: str,
                 runner: Optional[Callable[[dict], float]] = None,
                 autotune: Optional[bool] = None,
                 mesh: Optional[tuple] = None) -> dict:
@@ -275,10 +257,3 @@ def best_params(shape: MixerShape, dtype, device: str, *, kind: str = "tiles",
         return {p: best[p] for p in _KIND_PARAMS[kind]}
     return _DEFAULTS[kind](shape)
 
-
-def best_tiles(shape: MixerShape, dtype, device: str, *,
-               runner: Optional[Callable[[dict], float]] = None,
-               autotune: Optional[bool] = None) -> dict:
-    """Back-compat alias for the classic (block_m, block_n) search."""
-    return best_params(shape, dtype, device, kind="tiles", runner=runner,
-                       autotune=autotune)

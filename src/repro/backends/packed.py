@@ -2,12 +2,12 @@
 many-head/small-D lane packing and a custom VJP (kernels/flare_packed.py,
 DESIGN.md §12).
 
-This is the TPU training fast path: unlike the two-launch ``pallas`` backend
-it is grad-capable, so ``impl="auto"`` under training (``grad=True``) and the
-paper's D in {4, 8} regimes resolve here. The plan consults the autotune
-cache's ``packed`` kind, which searches the head-pack factor alongside the N
-tile. Off-TPU the kernels run in interpret mode — correct but slow, so
-"auto" only picks this backend on TPU; tests select it explicitly.
+This is the TPU FLARE fast path for training and inference alike: it is
+grad-capable, so ``MixerPolicy(requires_grad=True)`` resolves here on TPU
+as the bare policy does. The plan consults the autotune cache's ``packed``
+kind, which searches the head-pack factor alongside the N tile. Off-TPU the
+kernels run in interpret mode — correct but slow, so "auto" only picks this
+backend on TPU; tests select it explicitly.
 """
 from __future__ import annotations
 
@@ -72,9 +72,6 @@ register(MixerBackend(
                       dtypes=("float32", "bfloat16"), grads=True),
     plan=_plan,
     run=_run,
-    # beats the two-launch kernels wherever heads can share lanes; for
-    # D >= 128 there is nothing to pack, so the classic tiles keep the edge
-    score=lambda shape, device: (
-        (30.0 if shape.head_dim < 128 else 15.0) if device == "tpu" else 1.5),
+    score=lambda shape, device: 30.0 if device == "tpu" else 1.5,
     doc="single-launch packed-head fused kernels with custom VJP",
 ))

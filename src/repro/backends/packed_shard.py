@@ -110,8 +110,8 @@ def build_shard_plan(shape: MixerShape, mesh, seq_axes, lat_axes,
 def _plan(shape: MixerShape, mesh, dtype) -> MixerPlan:
     if mesh is None:
         raise ValueError(
-            "backend 'packed_shard' needs a mesh — pass one to resolve()/"
-            "run_mixer() or build a plan with dispatch.sharded_plan(mesh, "
+            "backend 'packed_shard' needs a mesh — pass one to "
+            "resolve_policy() or build a plan with dispatch.sharded_plan(mesh, "
             "seq_axes, lat_axes, shape=...)")
     seq, lat = default_axes(mesh)
     return build_shard_plan(shape, mesh, seq, lat, dtype)

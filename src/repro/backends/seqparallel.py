@@ -1,8 +1,7 @@
 """Sharded backends: shard_map sequence-parallel (1D) and seq x latent (2D)
-FLARE. Both require a mesh in the plan — "auto" never selects them; launch
-code obtains a plan from :func:`repro.core.dispatch.sharded_plan` (or the
-legacy ``("sp", mesh, axes)`` / ``("sp2d", mesh, sa, la)`` tuples, which the
-resolver aliases here).
+FLARE. Both require a mesh in the plan — "auto" selects them only when
+resolution is given a mesh; launch code obtains a plan from
+:func:`repro.core.dispatch.sharded_plan`.
 """
 from __future__ import annotations
 
@@ -20,8 +19,8 @@ from repro.core.dispatch import (
 def _plan_sp(shape: MixerShape, mesh, dtype) -> MixerPlan:
     if mesh is None:
         raise ValueError(
-            "backend 'seqparallel' needs a mesh — pass one to resolve()/"
-            "run_mixer() or build a plan with dispatch.sharded_plan(mesh, seq_axes)")
+            "backend 'seqparallel' needs a mesh — pass one to resolve_policy() "
+            "or build a plan with dispatch.sharded_plan(mesh, seq_axes)")
     # default: shard the token dim over every mesh axis
     return MixerPlan("seqparallel", {"mesh": mesh,
                                      "seq_axes": tuple(mesh.axis_names)})

@@ -7,21 +7,17 @@ describing *what it can do* (causal vs bidirectional contract, device kinds,
 dtype constraints, whether it needs a mesh) and *how to run* (a ``plan``
 builder that freezes shape/tile decisions, and a ``run`` callable).
 
-Call sites never branch on raw ``impl`` strings or mesh-carrying tuples:
-they hand whatever ``impl`` value they were given to :func:`resolve` (or the
-convenience wrappers :func:`run_mixer` / :func:`run_causal_mixer`) and this
-module normalizes it:
+This module is the registry lookup under :mod:`repro.core.policy`: callers
+select a mixer with a ``MixerPolicy`` or a ``MixerPlan`` there, and
+:func:`resolve` turns one preference entry into a ``(backend, plan)`` pair:
 
     "auto"                      -> best eligible backend for this device
-    "sdpa" | "materialized" |
-    "pallas" | ...              -> that backend, by (aliased) name
-    ("sp", mesh, axes)          -> legacy alias for the "seqparallel" backend
-    ("sp2d", mesh, sa, la)      -> legacy alias for the "seqlat" backend
+    "sdpa" | "packed" | ...     -> that registered backend, by name
     MixerPlan                   -> pre-resolved plan, run as-is
 
-Resolution happens at Python level (trace time), so the chosen backend and
-its tile plan are compile-time constants — exactly like hand-threading the
-strings used to be, minus the hand-threading.
+:func:`sharded_plan` builds the plan for a mesh. Resolution happens at
+Python level (trace time), so the chosen backend and its tile plan are
+compile-time constants.
 
 Backends live in :mod:`repro.backends`; importing that package populates the
 registry (lazily triggered here so ``repro.core`` stays import-light).
@@ -109,19 +105,10 @@ class MixerBackend:
 # ---------------------------------------------------------------------------
 
 _REGISTRY: dict = {}
-_ALIASES = {
-    # legacy spelling -> canonical backend name
-    "sp": "seqparallel",
-    "sp2d": "seqlat",
-    "stream": "causal_stream",
-    "causal": "causal_stream",
-}
 _LOADED = False
 
 
 def register(backend: MixerBackend) -> MixerBackend:
-    if backend.name in _ALIASES:
-        raise ValueError(f"backend name {backend.name!r} shadows an alias")
     _REGISTRY[backend.name] = backend
     return backend
 
@@ -135,9 +122,8 @@ def _ensure_loaded() -> None:
 
 def get_backend(name: str) -> MixerBackend:
     _ensure_loaded()
-    canonical = _ALIASES.get(name, name)
     try:
-        return _REGISTRY[canonical]
+        return _REGISTRY[name]
     except KeyError:
         raise ValueError(
             f"unknown mixer backend {name!r}; registered: {sorted(_REGISTRY)}"
@@ -191,18 +177,6 @@ def eligible(backend: MixerBackend, *, causal: bool, dtype, device: Optional[str
 # ---------------------------------------------------------------------------
 
 
-def _legacy_tuple_plan(impl: tuple) -> MixerPlan:
-    tag = impl[0]
-    if tag == "sp":
-        _, mesh, seq_axes = impl
-        return MixerPlan("seqparallel", {"mesh": mesh, "seq_axes": seq_axes})
-    if tag == "sp2d":
-        _, mesh, seq_axes, lat_axes = impl
-        return MixerPlan("seqlat", {"mesh": mesh, "seq_axes": seq_axes,
-                                    "lat_axes": lat_axes})
-    raise ValueError(f"unknown legacy impl tuple {impl!r}")
-
-
 def _check_contract(backend: MixerBackend, causal: bool, grad: bool = False) -> None:
     """Explicitly-named backends/plans still must satisfy the correctness
     contract: a bidirectional mixer on the causal path would silently leak
@@ -223,28 +197,25 @@ def _check_contract(backend: MixerBackend, causal: bool, grad: bool = False) -> 
             f"{[b.name for b in _REGISTRY.values() if b.caps.grads]}")
 
 
-def resolve(impl, *, shape: MixerShape, dtype, mesh=None, causal: bool = False,
+def resolve(name, *, shape: MixerShape, dtype, mesh=None, causal: bool = False,
             grad: bool = False):
-    """Normalize any ``impl`` value to a ``(MixerBackend, MixerPlan)`` pair.
+    """Turn ``"auto"``, a registered backend name or a ``MixerPlan`` into a
+    ``(MixerBackend, MixerPlan)`` pair.
 
     ``grad=True`` marks a differentiated call site (training): ``"auto"``
     only considers grad-capable backends, and naming a forward-only backend
     is a hard error rather than a trace-time autodiff failure."""
     _ensure_loaded()
-    if impl is None:
-        impl = "auto"
-    if isinstance(impl, MixerPlan):
-        backend = get_backend(impl.backend)
+    if isinstance(name, MixerPlan):
+        backend = get_backend(name.backend)
         _check_contract(backend, causal, grad)
-        return backend, impl
-    if isinstance(impl, tuple):
-        plan = _legacy_tuple_plan(impl)
-        backend = get_backend(plan.backend)
-        _check_contract(backend, causal, grad)
-        return backend, plan
-    if not isinstance(impl, str):
-        raise TypeError(f"impl must be str | tuple | MixerPlan, got {type(impl)!r}")
-    if impl == "auto":
+        return backend, name
+    if not isinstance(name, str):
+        raise TypeError(
+            f"resolve takes a backend name or a MixerPlan, got {type(name)!r}; "
+            "select a mixer with MixerPolicy(backends=(...)) or build a mesh "
+            "plan with dispatch.sharded_plan(mesh, ...)")
+    if name == "auto":
         dev = device_kind()
         cands = [b for b in _REGISTRY.values()
                  if eligible(b, causal=causal, dtype=dtype, device=dev, mesh=mesh,
@@ -267,16 +238,9 @@ def resolve(impl, *, shape: MixerShape, dtype, mesh=None, causal: bool = False,
         raise ValueError(
             "auto: every eligible backend rejected the shape at plan time:\n  "
             + "\n  ".join(errors))
-    backend = get_backend(impl)
+    backend = get_backend(name)
     _check_contract(backend, causal, grad)
     return backend, backend.plan(shape, mesh, dtype)
-
-
-def describe(impl, *, shape: MixerShape, dtype=jnp.float32, mesh=None,
-             causal: bool = False) -> str:
-    """Human/CSV-friendly 'which backend+plan would run' string."""
-    _, plan = resolve(impl, shape=shape, dtype=dtype, mesh=mesh, causal=causal)
-    return plan.describe()
 
 
 def sharded_plan(mesh, seq_axes: Sequence[str] | str,
@@ -317,29 +281,6 @@ def sharded_plan(mesh, seq_axes: Sequence[str] | str,
         return MixerPlan("seqparallel", {"mesh": mesh, "seq_axes": seq_axes})
     return MixerPlan("seqlat", {"mesh": mesh, "seq_axes": seq_axes,
                                 "lat_axes": lat_axes})
-
-
-# ---------------------------------------------------------------------------
-# Entry points used by call sites
-# ---------------------------------------------------------------------------
-
-
-def run_mixer(impl, q: jax.Array, k: jax.Array, v: jax.Array, *, mesh=None,
-              grad: bool = False) -> jax.Array:
-    """Bidirectional (set-mixer) FLARE: q [H,M,D], k/v [B,H,N,D] -> [B,H,N,D]."""
-    backend, plan = resolve(impl, shape=MixerShape.from_qkv(q, k), dtype=k.dtype,
-                            mesh=mesh, causal=False, grad=grad)
-    return backend.run(plan, q, k, v)
-
-
-def run_causal_mixer(impl, q: jax.Array, k: jax.Array, v: jax.Array, *,
-                     chunk_size: Optional[int] = None, grad: bool = False) -> jax.Array:
-    """Causal (LM-mixer) FLARE: token t sees only the prefix <= t."""
-    backend, plan = resolve(impl, shape=MixerShape.from_qkv(q, k), dtype=k.dtype,
-                            causal=True, grad=grad)
-    if chunk_size is not None:
-        plan = MixerPlan(plan.backend, {**plan.params, "chunk_size": chunk_size})
-    return backend.run(plan, q, k, v)
 
 
 # ---------------------------------------------------------------------------

@@ -19,8 +19,7 @@ API in repro.core.policy (DESIGN.md §10/§13): ``policy`` may be a
 requirement, dtype, autotune opt-in), a pre-resolved
 :class:`~repro.core.dispatch.MixerPlan` (the build-time product of
 ``resolve_policy`` — what model forwards receive), or ``None`` to use the
-ambient policy stack (``with mixer_policy(...):``). Legacy ``impl=`` strings
-and ``("sp", mesh, axes)`` tuples still resolve, with a DeprecationWarning.
+ambient policy stack (``with mixer_policy(...):``).
 
 Softmax statistics are fp32 with max subtraction (beyond-paper stability fix;
 mathematically identical — see DESIGN.md §9).
@@ -75,7 +74,6 @@ def flare_mixer(
     v: jax.Array,
     *,
     policy=None,
-    impl=None,
 ) -> jax.Array:
     """Multi-head FLARE token mixing.
 
@@ -85,10 +83,7 @@ def flare_mixer(
       v: [B, H, N, D] values from the deep ResMLP projection.
       policy: a MixerPolicy, a pre-resolved MixerPlan, or None to use the
         ambient policy stack (``with mixer_policy(...)``). Whether this call
-        must be differentiable is the policy's ``requires_grad`` field — the
-        old ``grad=`` kwarg is gone.
-      impl: deprecated alias accepting the legacy string/tuple spellings
-        (adapter in repro.core.policy; emits DeprecationWarning).
+        must be differentiable is the policy's ``requires_grad`` field.
 
     Returns:
       y: [B, H, N, D].
@@ -96,8 +91,6 @@ def flare_mixer(
     from repro.core.dispatch import MixerShape
     from repro.core.policy import resolve_policy, run_plan
 
-    if impl is not None:
-        policy = impl  # legacy spelling; policy_from() warns for str/tuple
     plan = resolve_policy(policy, MixerShape.from_qkv(q, k), k.dtype)
     return run_plan(plan, q, k, v)
 
@@ -165,13 +158,13 @@ def _merge_heads(x: jax.Array) -> jax.Array:
     return x.transpose(0, 2, 1, 3).reshape(b, n, h * d)
 
 
-def flare_layer(params: dict, x: jax.Array, *, policy=None, impl=None) -> jax.Array:
+def flare_layer(params: dict, x: jax.Array, *, policy=None) -> jax.Array:
     """x: [B, N, C] -> [B, N, C]. ``policy``: MixerPolicy | MixerPlan | None
-    (ambient stack); ``impl`` is the deprecated legacy spelling."""
+    (ambient stack)."""
     num_heads = params["q_latent"].shape[0]
     k = _split_heads(resmlp(params["k_proj"], x), num_heads)
     v = _split_heads(resmlp(params["v_proj"], x), num_heads)
-    y = flare_mixer(params["q_latent"].astype(x.dtype), k, v, policy=policy, impl=impl)
+    y = flare_mixer(params["q_latent"].astype(x.dtype), k, v, policy=policy)
     return dense(params["out_proj"], _merge_heads(y))
 
 
@@ -202,8 +195,7 @@ def init_flare_block(
     }
 
 
-def flare_block(params: dict, x: jax.Array, *, policy=None, impl=None) -> jax.Array:
-    x = x + flare_layer(params["mixer"], layernorm(params["ln1"], x), policy=policy,
-                        impl=impl)
+def flare_block(params: dict, x: jax.Array, *, policy=None) -> jax.Array:
+    x = x + flare_layer(params["mixer"], layernorm(params["ln1"], x), policy=policy)
     x = x + resmlp(params["mlp"], layernorm(params["ln2"], x))
     return x

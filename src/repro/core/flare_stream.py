@@ -210,7 +210,6 @@ def flare_causal_with_state(
     *,
     chunk_size: int = 256,
     mode: str = "factored",
-    impl: str | None = None,
     mask: jax.Array | None = None,  # [B, N] bool, True = real token
 ) -> tuple[FlareState, jax.Array]:
     """Causal FLARE over a sequence via a scan of chunked prefills,
@@ -221,14 +220,12 @@ def flare_causal_with_state(
     uses the associative-scan per-position states (O(T*M*D) memory, exact
     for arbitrary inputs). ``mode`` is a numerical-strategy knob *within*
     this backend — backend selection itself is a MixerPolicy concern
-    (repro.core.policy); ``impl`` is the deprecated alias for ``mode``.
+    (repro.core.policy).
 
     ``mask`` marks real tokens (serving prefill buckets right-pad prompts):
     the returned state is exactly the state of the masked prefix; outputs at
     masked positions are garbage the caller discards.
     """
-    if impl is not None:
-        mode = impl
     b, h, n, d = k.shape
     m = q.shape[1]
     chunk_size = min(chunk_size, n)
@@ -259,12 +256,10 @@ def flare_causal_with_state(
     return state, ys.transpose(1, 2, 0, 3, 4).reshape(b, h, n, d)
 
 
-def flare_causal(q, k, v, *, chunk_size: int = 256, mode: str = "factored",
-                 impl: str | None = None):
+def flare_causal(q, k, v, *, chunk_size: int = 256, mode: str = "factored"):
     """Training-time causal FLARE mixer (the flare_lm architecture and the
     long_500k-capable path). See flare_causal_with_state."""
-    return flare_causal_with_state(q, k, v, chunk_size=chunk_size, mode=mode,
-                                   impl=impl)[1]
+    return flare_causal_with_state(q, k, v, chunk_size=chunk_size, mode=mode)[1]
 
 
 def stream_insert_slots(pool: FlareState, part: FlareState,

@@ -23,20 +23,18 @@ makes that decision first-class data:
                   requires_grad=True):`` and every un-planned FLARE call in
                   scope resolves against grad-capable backends only.
 
-Legacy ``impl="sdpa"`` strings and ``("sp", mesh, axes)`` tuples keep
-working through an adapter here (they resolve to the same plans) but emit a
-``DeprecationWarning``: the spelling to migrate to is a ``MixerPolicy`` (or
-a pre-resolved ``MixerPlan``). The old ``grad=`` kwargs are gone — the
-policy carries ``requires_grad``, which is exactly what stops a training
-step from silently re-resolving onto a forward-only kernel mid-trace.
+A FLARE-mixer selector is exactly ``MixerPolicy | MixerPlan | None``
+(None = the ambient stack); a mesh plan comes from
+:func:`repro.core.dispatch.sharded_plan`. The policy carries
+``requires_grad``, which is what stops a training step from silently
+re-resolving onto a forward-only kernel mid-trace.
 
-See DESIGN.md §13 for the policy/plan lifecycle and the migration table.
+See DESIGN.md §13 for the policy/plan lifecycle.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import warnings
 from typing import Optional, Tuple, Union
 
 import jax
@@ -172,37 +170,23 @@ def mixer_policy(policy: Optional[MixerPolicy] = None, **overrides):
 
 
 # ---------------------------------------------------------------------------
-# Legacy adapter
+# The selector
 # ---------------------------------------------------------------------------
 
-PolicyLike = Union[MixerPolicy, MixerPlan, str, tuple, None]
+PolicyLike = Union[MixerPolicy, MixerPlan, None]
 
 
 def policy_from(value: PolicyLike) -> Union[MixerPolicy, MixerPlan]:
-    """Normalize any accepted selector to a MixerPolicy (or pass a
-    pre-resolved MixerPlan through). Raw ``impl`` strings and the
-    ``("sp", ...)``/``("sp2d", ...)`` tuples are the deprecated spellings;
-    they keep resolving but warn once per site."""
+    """The selector as a MixerPolicy (None -> the ambient policy) or the
+    pre-resolved MixerPlan it already is."""
     if value is None:
         return current_policy()
     if isinstance(value, (MixerPolicy, MixerPlan)):
         return value
-    if isinstance(value, str):
-        warnings.warn(
-            f"impl={value!r} is deprecated; use MixerPolicy(backends=({value!r},))"
-            " (see DESIGN.md §13 migration table)",
-            DeprecationWarning, stacklevel=3)
-        return current_policy().with_(backends=(value,))
-    if isinstance(value, tuple) and value and isinstance(value[0], str):
-        warnings.warn(
-            f"legacy impl tuple {value[0]!r} is deprecated; use "
-            "dispatch.sharded_plan(mesh, ...) or MixerPolicy(seq_axes=...) "
-            "(see DESIGN.md §13 migration table)",
-            DeprecationWarning, stacklevel=3)
-        return dispatch._legacy_tuple_plan(value)
     raise TypeError(
-        f"mixer policy must be MixerPolicy | MixerPlan | str | tuple | None, "
-        f"got {type(value)!r}")
+        f"mixer policy must be MixerPolicy | MixerPlan | None, got {value!r}; "
+        "name backends with MixerPolicy(backends=(...)) and build a mesh plan "
+        "with dispatch.sharded_plan(mesh, ...)")
 
 
 # ---------------------------------------------------------------------------

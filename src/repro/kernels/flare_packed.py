@@ -1,7 +1,7 @@
 """Single-launch packed-head Pallas FLARE mixer with a custom VJP.
 
-Three TPU-shaped optimizations over the two-launch kernels in ``flare.py``
-(DESIGN.md §12):
+Three TPU-shaped optimizations over a plain pair of encode and decode
+kernels, one head per lane group (DESIGN.md §12):
 
   * **Packed-head lane layout.** The paper's strong configs use many heads
     with tiny head dims (D in {4, 8}); padding each head's D to the 128-lane
@@ -28,10 +28,10 @@ Three TPU-shaped optimizations over the two-launch kernels in ``flare.py``
     on the Pallas path.
 
 Orientation note: every score tile is kept latent-major, [S, block_n] with
-S = pack*Mp, because (a) encode's online softmax reduces along lanes as in
-``flare.py`` and (b) decode's softmax over latents becomes a *sublane*
-segmented softmax (per row-block max/sum), which is far cheaper on TPU than
-lane-dimension segmentation.
+S = pack*Mp, because (a) encode's online softmax reduces along lanes, as a
+flash-attention kernel's does, and (b) decode's softmax over latents becomes
+a *sublane* segmented softmax (per row-block max/sum), which is far cheaper
+on TPU than lane-dimension segmentation.
 
 All padding (head count to a pack multiple, M to the sublane tile, N to the
 block boundary, lanes to 128) happens in plain-JAX wrapper code, so JAX

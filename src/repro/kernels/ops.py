@@ -4,12 +4,12 @@ Responsibilities:
   - flatten [B, H, ...] -> [G, ...] group layout the kernels expect,
   - pad D to the 128-lane boundary (exact: zero columns do not change
     q.k scores, and padded output columns are sliced away),
-  - pad the token/latent dims UP to the tile boundary instead of shrinking
+  - pad the sequence dims UP to the tile boundary instead of shrinking
     tiles (the old ``while n % bn: bn //= 2`` collapsed to 1-wide tiles for
     odd/prime N — exactly the unstructured-mesh sizes the paper targets).
-    Padding is exact: the kernels mask padded softmax columns (``n_valid`` /
-    ``m_valid`` / ``kv_valid``) and padded output rows are sliced away; the
-    causal kernel needs no mask because padded trailing tokens only influence
+    Padding is exact: the attention kernel masks padded softmax columns
+    (``kv_valid``) and padded output rows are sliced away; the causal
+    kernel needs no mask because padded trailing tokens only influence
     positions after themselves (DESIGN.md §11),
   - choose interpret mode automatically off-TPU so tests/benchmarks run on
     CPU, while TPU gets the compiled kernels.
@@ -20,17 +20,13 @@ the last-resort heuristic for direct calls.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.attention import flash_attention_pallas
-from repro.kernels.flare import flare_decode_pallas, flare_encode_pallas
 from repro.kernels.flare_causal import flare_causal_chunk_pallas
-from repro.kernels.flare_packed import flare_mixer_packed  # noqa: F401  (re-export:
-# the packed-head single-launch mixer is the third dispatch wrapper here)
 
 LANE = 128
 
@@ -60,36 +56,6 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 def _flatten_groups(x: jax.Array) -> jax.Array:
     b, h, n, d = x.shape
     return x.reshape(b * h, n, d)
-
-
-def flare_mixer_fused(
-    q: jax.Array,  # [H, M, D] latent queries
-    k: jax.Array,  # [B, H, N, D]
-    v: jax.Array,  # [B, H, N, D]
-    *,
-    block_m: int = 128,
-    block_n: int = 512,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Fused FLARE mixer via the encode/decode Pallas kernels.
-
-    The latent queries stay [H, M, D] in HBM: both kernels index the q block
-    by ``g % H`` in their BlockSpec index_map, so no [B, H, M, D] broadcast
-    is ever materialized."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    b, h, n, d = k.shape
-    m = q.shape[1]
-    # clip tiles to the problem, then pad the problem to the tile boundary
-    bm = min(block_m, m)
-    bn = min(block_n, n)
-    qh = _pad_to(_pad_lanes(q.astype(k.dtype)), 1, bm)   # [H, Mp, Dp]
-    kg = _pad_to(_pad_lanes(_flatten_groups(k)), 1, bn)
-    vg = _pad_to(_pad_lanes(_flatten_groups(v)), 1, bn)
-    z = flare_encode_pallas(qh, kg, vg, block_m=bm, block_n=bn, n_valid=n,
-                            interpret=interpret)
-    y = flare_decode_pallas(qh, kg, z, block_n=bn, m_valid=m, interpret=interpret)
-    return y[:, :n, :d].reshape(b, h, n, d)
 
 
 def flash_attention(

@@ -159,15 +159,12 @@ def _train_guard(loss_fn, train_error):
 
 
 def get_model(cfg: ModelConfig, *, policy=None, mesh=None,
-              seq_len_hint: Optional[int] = None, flare_impl=None) -> Model:
+              seq_len_hint: Optional[int] = None) -> Model:
     """policy: FLARE mixer-dispatch request — a MixerPolicy, a pre-resolved
     MixerPlan (e.g. from dispatch.sharded_plan), or None for the ambient
     policy stack. Resolved HERE, once; the returned model's step functions
     carry the plans and never re-resolve. ``mesh``/``seq_len_hint`` feed
-    resolution (sharded-backend selection, tile autotuning).
-    ``flare_impl`` is the deprecated legacy kwarg (string/tuple spellings)."""
-    if flare_impl is not None and policy is None:
-        policy = flare_impl  # legacy value; policy_from() warns on resolve
+    resolution (sharded-backend selection, tile autotuning)."""
     fam = cfg.family
     if fam in ("dense", "moe", "vlm", "flare_lm"):
         from repro.models import transformer as t

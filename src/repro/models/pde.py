@@ -67,20 +67,20 @@ def init_surrogate(key, mixer: str, *, in_dim: int, out_dim: int, dim: int,
 
 
 def surrogate_forward(params: dict, x: jax.Array, *, mixer: str = "flare",
-                      num_heads: int = 8, policy=None, impl=None,
+                      num_heads: int = 8, policy=None,
                       remat: str = "none") -> jax.Array:
     """x: [B, N, F_in] point features -> [B, N, F_out].
 
     ``policy`` is a MixerPolicy or — the get_model path — the MixerPlan
     resolved once at model build; None falls back to the ambient policy
-    stack. ``impl`` is the deprecated legacy string spelling. ``remat`` is
-    the config's per-block rematerialization mode (full | dots | none)."""
+    stack. ``remat`` is the config's per-block rematerialization mode
+    (full | dots | none)."""
     h = resmlp(params["in_proj"], x)
     if mixer == "perceiver":
         h = perceiver_forward(params["perceiver"], h, num_heads)
     else:
         apply = {
-            "flare": lambda p, y: flare_block(p, y, policy=policy, impl=impl),
+            "flare": lambda p, y: flare_block(p, y, policy=policy),
             "vanilla": lambda p, y: vanilla_block(p, y, num_heads),
             "linformer": lambda p, y: linformer_block(p, y, num_heads),
             "transolver": lambda p, y: transolver_block(p, y, num_heads),
@@ -100,7 +100,7 @@ def relative_l2(pred: jax.Array, target: jax.Array) -> jax.Array:
 
 
 def surrogate_loss(params, batch, *, mixer: str = "flare", num_heads: int = 8,
-                   policy=None, impl=None, remat: str = "none"):
+                   policy=None, remat: str = "none"):
     from repro.core.policy import mixer_policy
 
     # the loss is the differentiated entry point: the requires_grad scope
@@ -108,8 +108,7 @@ def surrogate_loss(params, batch, *, mixer: str = "flare", num_heads: int = 8,
     # were already resolved under requires_grad=True in get_model
     with mixer_policy(requires_grad=True):
         pred = surrogate_forward(params, batch["x"], mixer=mixer,
-                                 num_heads=num_heads, policy=policy, impl=impl,
-                                 remat=remat)
+                                 num_heads=num_heads, policy=policy, remat=remat)
     return relative_l2(pred, batch["y"])
 
 

@@ -139,8 +139,7 @@ class ServeEngine:
         if prefill_into is None and model.prefill is not None \
                 and model.init_caches is not None:
             # legacy compat: a model that ships only the full-batch `prefill`
-            # contract still serves, through the generic scatter adapter —
-            # mirrors the PR-3 `impl=` deprecation convention
+            # contract still serves, through the generic scatter adapter
             warnings.warn(
                 f"{model.cfg.name}: model has no prefill_into — falling back "
                 "to the legacy full-prefill + slot-scatter compat path; "

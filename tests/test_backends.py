@@ -1,9 +1,10 @@
-"""Mixer-backend registry: capability dispatch, aliases, parity, autotune.
+"""Mixer-backend registry: capability dispatch, parity, autotune.
 
 Parity contract: every registered non-sharded bidirectional backend must
 agree with the ``sdpa`` reference within tolerance across awkward shapes —
 odd/prime N (the unstructured-mesh sizes the paper targets, and exactly the
-case the old tile-halving degenerated on), M > N, and bf16 as well as fp32.
+case the old tile-halving degenerated on), M > N, and bf16 as well as fp32;
+every one that claims ``grads`` must agree with ``sdpa``'s gradients too.
 The causal backends are checked against the O(N^2) causal oracle.
 """
 import jax
@@ -12,8 +13,10 @@ import numpy as np
 import pytest
 
 from repro.core import dispatch
+from repro.core.dispatch import MixerPlan
 from repro.core.flare import flare_mixer
 from repro.core.flare_stream import flare_causal_ref
+from repro.core.policy import MixerPolicy, resolve_policy, run_plan
 
 KEY = jax.random.PRNGKey(0)
 
@@ -28,6 +31,12 @@ def _qkv(h=2, m=8, n=37, d=16, b=2, dtype=jnp.float32, scale=0.5):
 
 def _local_backends(causal):
     return [b.name for b in dispatch.backends(causal=causal, sharded=False)]
+
+
+def _grad_backends():
+    """Grad-capable non-sharded bidirectional backends, the reference aside."""
+    return [b.name for b in dispatch.backends(causal=False, sharded=False)
+            if b.caps.grads and b.name != "sdpa"]
 
 
 SHAPES = [
@@ -47,11 +56,29 @@ class TestParity:
         if not dispatch._dtype_ok(backend.caps, dtype):
             pytest.skip(f"{name} does not declare {jnp.dtype(dtype).name}")
         q, k, v = _qkv(dtype=dtype, **shape)
-        ref = flare_mixer(q, k, v, impl="sdpa").astype(jnp.float32)
-        out = flare_mixer(q, k, v, impl=name).astype(jnp.float32)
+        ref = flare_mixer(q, k, v, policy=MixerPlan("sdpa")).astype(jnp.float32)
+        out = flare_mixer(q, k, v, policy=MixerPolicy(backends=(name,)))
         tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=tol, rtol=tol)
+        np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                                   np.asarray(ref), atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("name", _grad_backends())
+    @pytest.mark.parametrize("shape", SHAPES, ids=lambda s: f"N{s['n']}M{s['m']}")
+    def test_grad_capable_backends_match_sdpa_grads(self, name, shape):
+        """A backend that claims ``grads`` gives sdpa's gradients (fp32)."""
+        q, k, v = _qkv(**shape)
+        w = jax.random.normal(jax.random.fold_in(KEY, 5), v.shape)  # cotangent
+        pol = MixerPolicy(backends=(name,), requires_grad=True)
+
+        def loss(policy):
+            return lambda q, k, v: jnp.sum(w * flare_mixer(q, k, v, policy=policy))
+
+        got = jax.grad(loss(pol), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(MixerPlan("sdpa")), argnums=(0, 1, 2))(q, k, v)
+        for g, r in zip(got, want):
+            scale = np.abs(np.asarray(r)).max() + 1e-12
+            np.testing.assert_allclose(np.asarray(g) / scale, np.asarray(r) / scale,
+                                       atol=1e-4, rtol=1e-4)
 
     @pytest.mark.parametrize("name", _local_backends(causal=True))
     @pytest.mark.parametrize("shape", [{"n": 37, "m": 8}, {"n": 16, "m": 48}],
@@ -59,7 +86,10 @@ class TestParity:
     def test_causal_matches_oracle(self, name, shape):
         q, k, v = _qkv(**shape)
         ref = flare_causal_ref(q, k, v)
-        out = dispatch.run_causal_mixer(name, q, k, v, chunk_size=8)
+        plan = resolve_policy(MixerPolicy(backends=(name,), chunk_size=8),
+                              dispatch.MixerShape.from_qkv(q, k), k.dtype,
+                              causal=True)
+        out = run_plan(plan, q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
@@ -72,24 +102,8 @@ class TestResolution:
         assert backend.name == plan.backend
         # "auto" must never pick a sharded backend without a mesh
         assert not backend.caps.sharded
-        y = flare_mixer(q, k, v, impl="auto")
+        y = flare_mixer(q, k, v, policy=MixerPolicy())
         assert y.shape == v.shape
-
-    def test_legacy_string_aliases(self):
-        """Every legacy string impl value keeps resolving."""
-        q, k, v = _qkv()
-        shape = dispatch.MixerShape.from_qkv(q, k)
-        for legacy in ("sdpa", "materialized", "pallas"):
-            backend, plan = dispatch.resolve(legacy, shape=shape, dtype=k.dtype)
-            assert backend.name == legacy == plan.backend
-
-    def test_legacy_tuple_aliases(self):
-        mesh = jax.sharding.Mesh(np.array(jax.devices()).reshape(1, 1), ("s", "l"))
-        shape = dispatch.MixerShape(2, 2, 8, 4, 8)
-        b1, p1 = dispatch.resolve(("sp", mesh, "s"), shape=shape, dtype=jnp.float32)
-        assert b1.name == "seqparallel" and p1.params["seq_axes"] == "s"
-        b2, p2 = dispatch.resolve(("sp2d", mesh, "s", "l"), shape=shape, dtype=jnp.float32)
-        assert b2.name == "seqlat" and p2.params["lat_axes"] == "l"
 
     def test_sharded_plan_decision(self):
         mesh = jax.sharding.Mesh(np.array(jax.devices()).reshape(1, 1), ("data", "model"))
@@ -108,11 +122,12 @@ class TestResolution:
         tokens — explicit names must hard-error, not silently run."""
         q, k, v = _qkv(n=16)
         shape = dispatch.MixerShape.from_qkv(q, k)
-        for name in ("sdpa", "pallas", "materialized"):
+        for name in ("sdpa", "paged", "materialized"):
             with pytest.raises(ValueError, match="not causal"):
                 dispatch.resolve(name, shape=shape, dtype=jnp.float32, causal=True)
             with pytest.raises(ValueError, match="not causal"):
-                dispatch.run_causal_mixer(name, q, k, v)
+                resolve_policy(MixerPolicy(backends=(name,)), shape, k.dtype,
+                               causal=True)
         # and the reverse: causal-only backends can't serve the set mixer
         with pytest.raises(ValueError, match="causal contract"):
             dispatch.resolve("causal_stream", shape=shape, dtype=jnp.float32)
@@ -127,14 +142,15 @@ class TestResolution:
         backend, plan = dispatch.resolve(
             "auto", shape=dispatch.MixerShape.from_qkv(q, k), dtype=k.dtype, mesh=mesh)
         assert backend.caps.sharded and plan.params["seq_axes"] == ("x",)
-        y = dispatch.run_mixer("auto", q, k, v, mesh=mesh)
-        ref = flare_mixer(q, k, v, impl="sdpa")
+        y = run_plan(resolve_policy(MixerPolicy(), dispatch.MixerShape.from_qkv(q, k),
+                                    k.dtype, mesh=mesh), q, k, v)
+        ref = flare_mixer(q, k, v, policy=MixerPlan("sdpa"))
         np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-5)
 
     def test_plan_describe_round_trips(self):
         shape = dispatch.MixerShape(1, 2, 300, 16, 8)
-        desc = dispatch.describe("pallas", shape=shape)
-        assert desc.startswith("pallas(") and "block_n=" in desc
+        desc = resolve_policy(MixerPolicy(backends=("packed",)), shape).describe()
+        assert desc.startswith("packed(") and "block_n=" in desc
 
 
 class TestAutotune:
@@ -147,19 +163,20 @@ class TestAutotune:
 
         calls = []
 
-        def runner(tiles):
-            calls.append(tiles)
+        def runner(params):
+            calls.append(params)
             # pretend 256-wide N tiles are fastest
-            return 0.001 if tiles["block_n"] == 256 else 0.002
+            return 0.001 if params["block_n"] == 256 else 0.002
 
-        best = autotune.measure_tiles(shape, jnp.float32, "cpu", runner)
+        best = autotune.measure_tiles(shape, jnp.float32, "cpu", runner,
+                                      kind="packed")
         assert best["block_n"] == 256 and calls
         # a fresh lookup (memory cache cleared) reads the JSON file
         autotune._MEM_CACHE.clear()
-        got = autotune.best_tiles(shape, jnp.float32, "cpu")
-        assert got == {"block_m": best["block_m"], "block_n": 256}
-        # and the pallas backend plan consumes it
-        _, plan = dispatch.resolve("pallas", shape=shape, dtype=jnp.float32)
+        got = autotune.best_params(shape, jnp.float32, "cpu", kind="packed")
+        assert got == {"block_n": 256, "pack": best["pack"]}
+        # and the packed backend plan consumes it
+        _, plan = dispatch.resolve("packed", shape=shape, dtype=jnp.float32)
         assert plan.params["block_n"] == 256
 
     def test_heuristic_without_cache(self, tmp_path, monkeypatch):
@@ -169,5 +186,5 @@ class TestAutotune:
         monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
         autotune._MEM_CACHE.clear()
         shape = dispatch.MixerShape(1, 2, 37, 8, 16)
-        tiles = autotune.best_tiles(shape, jnp.float32, "cpu")
-        assert tiles["block_m"] >= 8 and tiles["block_n"] >= 128
+        params = autotune.best_params(shape, jnp.float32, "cpu", kind="packed")
+        assert params["pack"] >= 1 and params["block_n"] >= 128

@@ -55,11 +55,8 @@ y_sp = sp(q, k, v)
 y_ref = flare_mixer(q, k, v)
 np.testing.assert_allclose(np.asarray(y_sp), np.asarray(y_ref), atol=1e-5)
 
-# same math through the backend registry (legacy tuple alias), and the
-# sharded_plan helper must map this mesh onto the same backend
+# the sharded_plan helper must map this mesh onto the same backend
 from repro.core.dispatch import sharded_plan
-y_legacy = flare_mixer(q, k, v, impl=("sp", mesh, "seq"))
-np.testing.assert_allclose(np.asarray(y_legacy), np.asarray(y_ref), atol=1e-5)
 assert sharded_plan(mesh, ("seq",), lat_axes="seq").backend == "seqparallel"
 print("PASS")
 """)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.core.dispatch import MixerPlan
 from repro.core.flare import (
     flare_block,
     flare_dense_operator,
@@ -30,8 +31,8 @@ class TestOperatorEquivalence:
     def test_sdpa_equals_materialized(self):
         """Fig. 3 (two SDPA calls) == Fig. 7 (materialized weights)."""
         q, k, v = _qkv()
-        y1 = flare_mixer(q, k, v, impl="sdpa")
-        y2 = flare_mixer(q, k, v, impl="materialized")
+        y1 = flare_mixer(q, k, v, policy=MixerPlan("sdpa"))
+        y2 = flare_mixer(q, k, v, policy=MixerPlan("materialized"))
         np.testing.assert_allclose(y1, y2, atol=1e-5)
 
     def test_mixer_equals_dense_operator(self):

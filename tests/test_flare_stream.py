@@ -57,10 +57,10 @@ def test_prefix_causality_exact_path():
     """Output at t must not depend on tokens > t — even under adversarial
     future values (the exact path's guarantee)."""
     q, k, v = _qkv(n=16)
-    y_full = flare_causal(q, k, v, chunk_size=8, impl="exact")
+    y_full = flare_causal(q, k, v, chunk_size=8, mode="exact")
     k2 = k.at[:, :, 12:].set(99.0)
     v2 = v.at[:, :, 12:].set(-99.0)
-    y_pre = flare_causal(q, k2, v2, chunk_size=8, impl="exact")
+    y_pre = flare_causal(q, k2, v2, chunk_size=8, mode="exact")
     np.testing.assert_allclose(y_full[:, :, :12], y_pre[:, :, :12], atol=1e-5)
 
 
@@ -68,17 +68,17 @@ def test_prefix_causality_factored_path():
     """The factored path is causal within its bounded-score contract
     (future scores within ~85 nats of the running max)."""
     q, k, v = _qkv(n=16)
-    y_full = flare_causal(q, k, v, chunk_size=8, impl="factored")
+    y_full = flare_causal(q, k, v, chunk_size=8, mode="factored")
     k2 = k.at[:, :, 12:].set(4.0)   # large-but-realistic future change
     v2 = v.at[:, :, 12:].set(-4.0)
-    y_pre = flare_causal(q, k2, v2, chunk_size=8, impl="factored")
+    y_pre = flare_causal(q, k2, v2, chunk_size=8, mode="factored")
     np.testing.assert_allclose(y_full[:, :, :12], y_pre[:, :, :12], atol=1e-5)
 
 
 def test_factored_equals_exact_realistic():
     q, k, v = _qkv(n=32, scale=1.5)
-    y_f = flare_causal(q, k, v, chunk_size=8, impl="factored")
-    y_e = flare_causal(q, k, v, chunk_size=8, impl="exact")
+    y_f = flare_causal(q, k, v, chunk_size=8, mode="factored")
+    y_e = flare_causal(q, k, v, chunk_size=8, mode="exact")
     np.testing.assert_allclose(y_f, y_e, atol=1e-5)
 
 

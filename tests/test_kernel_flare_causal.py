@@ -33,7 +33,7 @@ def test_kernel_matches_oracle(b, h, n, m, d, tile):
 def test_kernel_matches_factored_jnp_path():
     q, k, v = _qkv(1, 2, 64, 16, 8, scale=1.5)
     y_k = flare_causal_fused(q, k, v, tile=16)
-    y_j = flare_causal(q, k, v, chunk_size=16, impl="factored")
+    y_j = flare_causal(q, k, v, chunk_size=16, mode="factored")
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_j), atol=2e-5)
 
 

@@ -4,9 +4,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.dispatch import MixerPlan
 from repro.core.flare import flare_mixer
 from repro.kernels import ref
-from repro.kernels.ops import flare_mixer_fused, flash_attention
+from repro.kernels.flare_packed import flare_mixer_packed
+from repro.kernels.ops import flash_attention
 
 KEY = jax.random.PRNGKey(2)
 
@@ -27,24 +29,10 @@ def test_flare_kernel_sweep(b, h, n, m, d, dtype):
     q = (jax.random.normal(ks[0], (h, m, d)) * 0.5).astype(dtype)
     k = (jax.random.normal(ks[1], (b, h, n, d)) * 0.5).astype(dtype)
     v = jax.random.normal(ks[2], (b, h, n, d)).astype(dtype)
-    y = flare_mixer_fused(q, k, v, block_m=16, block_n=32)
-    y_ref = flare_mixer(q, k, v, impl="sdpa")
+    y = flare_mixer_packed(q, k, v, block_n=32)
+    y_ref = flare_mixer(q, k, v, policy=MixerPlan("sdpa"))
     np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_ref, np.float32),
                                **_tol(dtype))
-
-
-def test_flare_encode_decode_against_oracles():
-    g, m, n, d = 4, 16, 128, 8
-    from repro.kernels.flare import flare_decode_pallas, flare_encode_pallas
-
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (g, m, d)) * 0.5
-    k = jax.random.normal(ks[1], (g, n, d)) * 0.5
-    v = jax.random.normal(ks[2], (g, n, d))
-    z = flare_encode_pallas(q, k, v, block_m=8, block_n=32, interpret=True)
-    np.testing.assert_allclose(z, ref.flare_encode_ref(q, k, v), atol=1e-5)
-    y = flare_decode_pallas(q, k, z, block_n=32, interpret=True)
-    np.testing.assert_allclose(y, ref.flare_decode_ref(q, k, z), atol=1e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -67,13 +55,14 @@ def test_flash_kernel_sweep(sq, skv, d, causal, window, dtype):
 
 
 def test_lane_padding_is_exact():
-    """ops.py zero-pads D to 128 lanes — must be exactly invisible."""
+    """The packed wrapper zero-pads pack*D to 128 lanes (D=8 at pack=1) —
+    must be exactly invisible."""
     b, h, n, m, d = 1, 2, 64, 16, 8
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (h, m, d)) * 0.5
     k = jax.random.normal(ks[1], (b, h, n, d)) * 0.5
     v = jax.random.normal(ks[2], (b, h, n, d))
-    y1 = flare_mixer_fused(q, k, v, block_m=16, block_n=32)
+    y1 = flare_mixer_packed(q, k, v, pack=1, block_n=32)
     y2 = flare_mixer(q, k, v)
     np.testing.assert_allclose(y1, y2, atol=1e-5)
     assert y1.shape[-1] == d  # padding sliced back off

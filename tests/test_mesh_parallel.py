@@ -61,7 +61,7 @@ def test_packed_shard_1d_mesh_and_registry_route():
     run_in_cpu_mesh(r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.distributed.sharding import make_mesh
-from repro.core.dispatch import run_mixer, resolve, MixerShape
+from repro.core.dispatch import resolve, MixerShape
 from repro.kernels.flare_packed import flare_mixer_packed
 
 mesh = make_mesh((4,), ("data",))

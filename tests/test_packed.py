@@ -10,8 +10,9 @@ import numpy as np
 import pytest
 
 from repro.core import dispatch
+from repro.core.dispatch import MixerPlan
 from repro.core.flare import flare_mixer
-from repro.core.policy import MixerPolicy
+from repro.core.policy import MixerPolicy, resolve_policy
 from repro.kernels.flare_packed import flare_mixer_packed, heuristic_pack
 from repro.obs.metrics import REGISTRY
 
@@ -41,7 +42,7 @@ class TestForwardParity:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
     def test_matches_sdpa(self, shape, dtype):
         q, k, v = _qkv(dtype=dtype, **shape)
-        ref = flare_mixer(q, k, v, impl="sdpa").astype(jnp.float32)
+        ref = flare_mixer(q, k, v, policy=MixerPlan("sdpa")).astype(jnp.float32)
         out = flare_mixer_packed(q, k, v, block_n=32).astype(jnp.float32)
         tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=tol, rtol=tol)
@@ -51,7 +52,7 @@ class TestForwardParity:
         """Packed vs materialized backend across explicit pack factors —
         the layout transform must be invisible at every pack."""
         q, k, v = _qkv(h=4, m=8, n=50, d=8)
-        ref = flare_mixer(q, k, v, impl="materialized")
+        ref = flare_mixer(q, k, v, policy=MixerPlan("materialized"))
         out = flare_mixer_packed(q, k, v, pack=pack, block_n=16)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5)
@@ -76,7 +77,7 @@ class TestGradParity:
             return jnp.sum(w * flare_mixer_packed(q, k, v, block_n=32))
 
         def loss_ref(q, k, v):
-            return jnp.sum(w * flare_mixer(q, k, v, impl="sdpa"))
+            return jnp.sum(w * flare_mixer(q, k, v, policy=MixerPlan("sdpa")))
 
         gp = jax.grad(loss_packed, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -343,10 +344,10 @@ class TestDispatch:
             assert best.name == ("packed" if dev == "tpu" else "sdpa")
 
     def test_auto_on_tpu_prefers_packed_for_small_d(self):
-        """Acceptance: impl="auto" on TPU resolves to the packed backend for
-        D < 128 (scored, not device-run — CPU CI has no TPU)."""
+        """Acceptance: the bare policy on TPU resolves to the packed backend
+        at every head dim (scored, not device-run — CPU CI has no TPU)."""
         for d, expect in ((4, "packed"), (8, "packed"), (64, "packed"),
-                          (128, "pallas")):
+                          (128, "packed")):
             shape = dispatch.MixerShape(2, 4, 1024, 64, d)
             cands = [b for b in dispatch.backends(causal=False, sharded=False)
                      if dispatch.eligible(b, causal=False, dtype=jnp.float32,
@@ -357,7 +358,7 @@ class TestDispatch:
     def test_named_forward_only_backend_errors_under_grad(self):
         shape = dispatch.MixerShape(1, 2, 32, 8, 8)
         with pytest.raises(ValueError, match="forward-only"):
-            dispatch.resolve("pallas", shape=shape, dtype=jnp.float32, grad=True)
+            dispatch.resolve("paged", shape=shape, dtype=jnp.float32, grad=True)
         with pytest.raises(ValueError, match="forward-only"):
             dispatch.resolve("causal_pallas", shape=shape, dtype=jnp.float32,
                              causal=True, grad=True)
@@ -367,7 +368,7 @@ class TestDispatch:
 
     def test_plan_describe_includes_pack(self):
         shape = dispatch.MixerShape(1, 4, 300, 16, 8)
-        desc = dispatch.describe("packed", shape=shape)
+        desc = resolve_policy(MixerPolicy(backends=("packed",)), shape).describe()
         assert desc.startswith("packed(") and "pack=" in desc
 
 
@@ -395,9 +396,10 @@ class TestPackAutotune:
         autotune._MEM_CACHE.clear()
         got = autotune.best_params(shape, jnp.float32, "tpu", kind="packed")
         assert got == {"block_n": 128, "pack": 8}
-        # the packed and tiles kinds must not collide in the cache
-        tiles = autotune.best_params(shape, jnp.float32, "tpu", kind="tiles")
-        assert "pack" not in tiles
+        # a per-shard (mesh) lookup must not hit the single-device entry
+        meshed = autotune.best_params(shape, jnp.float32, "tpu", kind="packed",
+                                      autotune=False, mesh=(2, 2))
+        assert meshed == autotune.default_packed(shape)
 
     def test_store_merges_concurrent_writers(self, tmp_path, monkeypatch):
         """Another process's entries written between our load and store must
@@ -410,18 +412,20 @@ class TestPackAutotune:
         monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
         autotune._MEM_CACHE.clear()
         shape = dispatch.MixerShape(1, 2, 300, 16, 8)
-        autotune.measure_tiles(shape, jnp.float32, "cpu", lambda t: 0.001)
+        autotune.measure_tiles(shape, jnp.float32, "cpu", lambda t: 0.001,
+                               kind="packed")
         # simulate a concurrent process appending its own key directly
         data = json.loads(path.read_text())
         data["other|proc|key"] = {"block_m": 1, "block_n": 2}
         path.write_text(json.dumps(data))
         # our next store (stale in-memory view) must keep the foreign key
         shape2 = dispatch.MixerShape(1, 2, 600, 32, 8)
-        autotune.measure_tiles(shape2, jnp.float32, "cpu", lambda t: 0.001)
+        autotune.measure_tiles(shape2, jnp.float32, "cpu", lambda t: 0.001,
+                               kind="packed")
         final = json.loads(path.read_text())
         assert "other|proc|key" in final
-        assert autotune.cache_key(shape, jnp.float32, "cpu") in final
-        assert autotune.cache_key(shape2, jnp.float32, "cpu") in final
+        assert autotune.cache_key(shape, jnp.float32, "cpu", "packed") in final
+        assert autotune.cache_key(shape2, jnp.float32, "cpu", "packed") in final
 
     def test_corrupt_cache_falls_back_to_heuristic(self, tmp_path, monkeypatch):
         from repro.backends import autotune
@@ -431,15 +435,15 @@ class TestPackAutotune:
         path.write_text("{ not json !!")
         autotune._MEM_CACHE.clear()
         shape = dispatch.MixerShape(1, 2, 37, 8, 16)
-        tiles = autotune.best_tiles(shape, jnp.float32, "cpu")
-        assert tiles["block_m"] >= 8 and tiles["block_n"] >= 128
         packed = autotune.best_params(shape, jnp.float32, "cpu", kind="packed")
-        assert packed["pack"] >= 1
+        assert packed["pack"] >= 1 and packed["block_n"] >= 128
         # a store over the corrupt file recovers it
-        autotune.measure_tiles(shape, jnp.float32, "cpu", lambda t: 0.001)
+        autotune.measure_tiles(shape, jnp.float32, "cpu", lambda t: 0.001,
+                               kind="packed")
         import json
 
-        assert autotune.cache_key(shape, jnp.float32, "cpu") in json.loads(path.read_text())
+        assert autotune.cache_key(shape, jnp.float32, "cpu", "packed") in \
+            json.loads(path.read_text())
 
     def test_malformed_entry_is_a_miss(self, tmp_path, monkeypatch):
         import json
@@ -450,10 +454,11 @@ class TestPackAutotune:
         monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
         shape = dispatch.MixerShape(1, 2, 37, 8, 16)
         path.write_text(json.dumps(
-            {autotune.cache_key(shape, jnp.float32, "cpu"): {"block_m": "??"}}))
+            {autotune.cache_key(shape, jnp.float32, "cpu", "packed"):
+             {"block_n": "??"}}))
         autotune._MEM_CACHE.clear()
-        tiles = autotune.best_tiles(shape, jnp.float32, "cpu")
-        assert tiles["block_n"] >= 128  # heuristic, not a crash
+        params = autotune.best_params(shape, jnp.float32, "cpu", kind="packed")
+        assert params["block_n"] >= 128  # heuristic, not a crash
 
 
 class TestTrainingSmoke:

@@ -388,8 +388,7 @@ def test_coalesced_prefill_on_paged_pool():
 
 def test_legacy_prefill_compat_warns_and_serves():
     """A model exposing only the legacy full-batch `prefill` still serves,
-    through the deprecated compat adapter — mirroring the PR-3 `impl=`
-    convention the warning text points past."""
+    through the deprecated compat adapter the warning text points past."""
     import dataclasses
 
     model, params = _model("qwen2_1_5b")
@@ -443,7 +442,9 @@ def test_paged_attention_single_query_decode_shape():
 
 
 def test_paged_backend_registered_and_matches_sdpa():
-    from repro.core.dispatch import backends, get_backend, run_mixer
+    from repro.core.dispatch import MixerPlan, backends, get_backend
+    from repro.core.flare import flare_mixer
+    from repro.core.policy import MixerPolicy
 
     assert any(b.name == "paged" for b in backends())
     b = get_backend("paged")
@@ -452,8 +453,9 @@ def test_paged_backend_registered_and_matches_sdpa():
     q = jnp.asarray(rng.normal(size=(2, 8, 16)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(2, 2, 19, 16)), jnp.float32)  # odd N pads
     v = jnp.asarray(rng.normal(size=(2, 2, 19, 16)), jnp.float32)
-    np.testing.assert_allclose(np.asarray(run_mixer("paged", q, k, v)),
-                               np.asarray(run_mixer("sdpa", q, k, v)),
+    paged = flare_mixer(q, k, v, policy=MixerPolicy(backends=("paged",)))
+    ref = flare_mixer(q, k, v, policy=MixerPlan("sdpa"))
+    np.testing.assert_allclose(np.asarray(paged), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 
 

@@ -1,5 +1,5 @@
 """Plan-first MixerPolicy API (DESIGN.md §13): the policy stack, build-time
-resolution, hashability (jit-static), legacy-alias deprecation, and the
+resolution, hashability (jit-static), the one selector vocabulary, and the
 requires_grad safety contract (a training policy can never resolve onto a
 forward-only kernel, bidirectional or causal).
 """
@@ -108,34 +108,41 @@ class TestHashability:
         assert len(leaves) == 1  # only x — the policy is static structure
 
 
-class TestLegacyAliases:
-    def test_string_impl_warns_and_resolves(self):
-        with pytest.deprecated_call():
-            plan = resolve_policy("sdpa", SHAPE, jnp.float32)
-        assert plan.backend == "sdpa"
+def _pde_cfg():
+    from repro.config import AttnConfig, ModelConfig
 
-    def test_legacy_tuple_warns_and_resolves(self):
-        mesh = jax.sharding.Mesh(np.array(jax.devices()).reshape(1, 1), ("s", "l"))
-        with pytest.deprecated_call():
-            plan = resolve_policy(("sp", mesh, "s"), SHAPE, jnp.float32)
-        assert plan.backend == "seqparallel" and plan.params["seq_axes"] == "s"
+    return ModelConfig(name="t", family="pde", num_layers=1, d_model=32,
+                       d_ff=32, vocab=0, attn=AttnConfig(kind="none"),
+                       flare_heads=4, flare_latents=8)
 
-    def test_flare_mixer_impl_kwarg_warns(self):
-        q, k, v = _qkv()
-        with pytest.deprecated_call():
-            y = flare_mixer(q, k, v, impl="sdpa")
-        assert y.shape == v.shape
 
-    def test_get_model_flare_impl_kwarg(self):
-        from repro.config import AttnConfig, ModelConfig
-        from repro.models.api import get_model
+def _one_device_mesh():
+    return jax.sharding.Mesh(np.array(jax.devices()).reshape(1, 1), ("s", "l"))
 
-        cfg = ModelConfig(name="t", family="pde", num_layers=1, d_model=32,
-                          d_ff=32, vocab=0, attn=AttnConfig(kind="none"),
-                          flare_heads=4, flare_latents=8)
-        with pytest.deprecated_call():
-            model = get_model(cfg, flare_impl="sdpa")
-        assert model.plans["infer"].backend == "sdpa"
+
+def _get_model_with_string():
+    from repro.models.api import get_model
+
+    get_model(_pde_cfg(), policy="sdpa")
+
+
+@pytest.mark.parametrize("call", [
+    lambda: resolve_policy("sdpa", SHAPE, jnp.float32),
+    lambda: resolve_policy(("sp", _one_device_mesh(), "s"), SHAPE, jnp.float32),
+    lambda: flare_mixer(*_qkv(), policy="sdpa"),
+    _get_model_with_string,
+    lambda: dispatch.resolve(("sp", _one_device_mesh(), "s"), shape=SHAPE,
+                             dtype=jnp.float32),
+    lambda: dispatch.get_backend("sp"),
+], ids=["resolve_policy-str", "resolve_policy-tuple", "flare_mixer-str",
+        "get_model-str", "dispatch.resolve-tuple", "get_backend-alias"])
+def test_removed_selectors_are_rejected(call):
+    """A selector is MixerPolicy | MixerPlan | None: the removed string and
+    tuple spellings, and the old backend aliases, are errors that name the
+    spelling to use."""
+    with pytest.raises((TypeError, ValueError),
+                       match="MixerPolicy|unknown mixer backend"):
+        call()
 
 
 class TestResolution:
@@ -154,7 +161,7 @@ class TestResolution:
                            causal=True)
 
     def test_exhausted_preference_reports_reasons(self):
-        pol = MixerPolicy(backends=("pallas", "causal_pallas"),
+        pol = MixerPolicy(backends=("paged", "causal_pallas"),
                           requires_grad=True)
         with pytest.raises(ValueError, match="preference order"):
             resolve_policy(pol, SHAPE, jnp.float32)
@@ -208,7 +215,7 @@ class TestRequiresGradContract:
     """Regression: a requires_grad=True policy can NEVER resolve the
     forward-only kernels, on either contract, on any device kind."""
 
-    @pytest.mark.parametrize("name,causal", [("pallas", False),
+    @pytest.mark.parametrize("name,causal", [("paged", False),
                                              ("causal_pallas", True)])
     def test_named_forward_only_backend_is_an_error(self, name, causal):
         pol = MixerPolicy(backends=(name,), requires_grad=True)
@@ -224,14 +231,14 @@ class TestRequiresGradContract:
                                           device=dev, grad=True)]
             assert cands, dev
             assert all(b.caps.grads for b in cands)
-            assert "pallas" not in {b.name for b in cands}
+            assert "paged" not in {b.name for b in cands}
             assert "causal_pallas" not in {b.name for b in cands}
         # and the actual resolution on this device
         plan = resolve_policy(pol, SHAPE, jnp.float32, causal=causal)
         assert dispatch.get_backend(plan.backend).caps.grads
 
     def test_preference_order_skips_forward_only_under_grad(self):
-        pol = MixerPolicy(backends=("pallas", "sdpa"), requires_grad=True)
+        pol = MixerPolicy(backends=("paged", "sdpa"), requires_grad=True)
         assert resolve_policy(pol, SHAPE, jnp.float32).backend == "sdpa"
         polc = MixerPolicy(backends=("causal_pallas", "causal_stream"),
                            requires_grad=True)
@@ -239,7 +246,7 @@ class TestRequiresGradContract:
                               causal=True).backend == "causal_stream"
 
     def test_ensure_plan_rechecks_grad_contract(self):
-        plan = MixerPlan("pallas", {"block_m": 128, "block_n": 512})
+        plan = MixerPlan("paged")
         with mixer_policy(requires_grad=True):
             with pytest.raises(ValueError, match="forward-only"):
                 ensure_plan(plan, SHAPE, jnp.float32)
@@ -333,8 +340,8 @@ class TestAutotuneVersionedKeys:
     def test_cache_key_carries_runtime_version(self):
         from repro.backends import autotune
 
-        key = autotune.cache_key(SHAPE, jnp.float32, "cpu")
-        legacy = autotune.legacy_cache_key(SHAPE, jnp.float32, "cpu")
+        key = autotune.cache_key(SHAPE, jnp.float32, "cpu", "packed")
+        legacy = autotune.legacy_cache_key(SHAPE, jnp.float32, "cpu", "packed")
         assert key.startswith(legacy) and autotune.runtime_version() in key
         assert "jax" in autotune.runtime_version()
 
@@ -346,10 +353,10 @@ class TestAutotuneVersionedKeys:
         path = tmp_path / "tiles.json"
         monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
         autotune._MEM_CACHE.clear()
-        legacy_key = autotune.legacy_cache_key(SHAPE, jnp.float32, "cpu")
-        path.write_text(json.dumps({legacy_key: {"block_m": 16, "block_n": 384}}))
-        got = autotune.best_tiles(SHAPE, jnp.float32, "cpu")
-        assert got == {"block_m": 16, "block_n": 384}
+        legacy_key = autotune.legacy_cache_key(SHAPE, jnp.float32, "cpu", "packed")
+        path.write_text(json.dumps({legacy_key: {"block_n": 384, "pack": 2}}))
+        got = autotune.best_params(SHAPE, jnp.float32, "cpu", kind="packed")
+        assert got == {"block_n": 384, "pack": 2}
 
     def test_new_measurements_store_versioned(self, tmp_path, monkeypatch):
         import json
@@ -360,12 +367,14 @@ class TestAutotuneVersionedKeys:
         monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
         autotune._MEM_CACHE.clear()
         autotune.measure_tiles(SHAPE, jnp.float32, "cpu",
-                               lambda t: 0.001 if t["block_n"] == 256 else 0.002)
+                               lambda t: 0.001 if t["pack"] == 2 else 0.002,
+                               kind="packed")
         data = json.loads(path.read_text())
-        assert list(data) == [autotune.cache_key(SHAPE, jnp.float32, "cpu")]
+        assert list(data) == [autotune.cache_key(SHAPE, jnp.float32, "cpu", "packed")]
         # versioned winner is read back after a cold start
         autotune._MEM_CACHE.clear()
-        assert autotune.best_tiles(SHAPE, jnp.float32, "cpu")["block_n"] == 256
+        assert autotune.best_params(SHAPE, jnp.float32, "cpu",
+                                    kind="packed")["pack"] == 2
 
     def test_versioned_entry_wins_over_legacy(self, tmp_path, monkeypatch):
         import json
@@ -376,13 +385,13 @@ class TestAutotuneVersionedKeys:
         monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
         autotune._MEM_CACHE.clear()
         path.write_text(json.dumps({
-            autotune.legacy_cache_key(SHAPE, jnp.float32, "cpu"):
-                {"block_m": 8, "block_n": 128},
-            autotune.cache_key(SHAPE, jnp.float32, "cpu"):
-                {"block_m": 32, "block_n": 512},
+            autotune.legacy_cache_key(SHAPE, jnp.float32, "cpu", "packed"):
+                {"block_n": 128, "pack": 1},
+            autotune.cache_key(SHAPE, jnp.float32, "cpu", "packed"):
+                {"block_n": 512, "pack": 2},
         }))
-        assert autotune.best_tiles(SHAPE, jnp.float32, "cpu") == {
-            "block_m": 32, "block_n": 512}
+        assert autotune.best_params(SHAPE, jnp.float32, "cpu", kind="packed") == {
+            "block_n": 512, "pack": 2}
 
     def test_policy_autotune_optin_scopes_enablement(self, monkeypatch):
         from repro.backends import autotune

@@ -74,7 +74,7 @@ def test_flare_lm_end_to_end():
 
 
 def test_kernel_path_matches_sdpa_path():
-    """The pallas-plan forward == the sdpa-plan forward on the same params."""
+    """The packed-plan forward == the sdpa-plan forward on the same params."""
     from repro.core.policy import MixerPolicy
 
     params = pde.init_surrogate(KEY, "flare", in_dim=3, out_dim=1, dim=32,
@@ -83,7 +83,7 @@ def test_kernel_path_matches_sdpa_path():
     y1 = pde.surrogate_forward(params, x, mixer="flare", num_heads=4,
                                policy=MixerPolicy(backends=("sdpa",)))
     y2 = pde.surrogate_forward(params, x, mixer="flare", num_heads=4,
-                               policy=MixerPolicy(backends=("pallas",)))
+                               policy=MixerPolicy(backends=("packed",)))
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-4)
 
 
