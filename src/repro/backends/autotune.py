@@ -179,11 +179,13 @@ _KIND_PARAMS = {
 def packed_candidates(shape: MixerShape) -> list:
     """(block_n, pack) pairs for the packed-head fused backend: every lane-
     filling pack factor that does not exceed the head count, crossed with
-    MXU-aligned N tiles."""
+    the N tiles its kernels can take with tokens on lanes: power-of-two
+    multiples of 128 narrower than N, and the whole N up to 1024."""
     d = max(1, shape.head_dim)
     max_pack = max(1, min(128 // d, shape.heads))
     packs = sorted({p for p in (1, 2, 4, 8, 16, 32) if p <= max_pack} | {max_pack})
-    bns = [b for b in _pow2s(128, 1024) if b <= max(128, shape.tokens)] or [128]
+    n = max(1, shape.tokens)
+    bns = [b for b in _pow2s(128, 1024) if b < n] + ([n] if n <= 1024 else [])
     return [{"block_n": bn, "pack": p} for p in packs for bn in bns]
 
 

@@ -55,8 +55,9 @@ def test_flash_kernel_sweep(sq, skv, d, causal, window, dtype):
 
 
 def test_lane_padding_is_exact():
-    """The packed wrapper zero-pads pack*D to 128 lanes (D=8 at pack=1) —
-    must be exactly invisible."""
+    """At pack=1 and D=8 the packed kernels read each head's 8 channel rows
+    of channel-major K and V (no lane padding) — the layout must be
+    exactly invisible."""
     b, h, n, m, d = 1, 2, 64, 16, 8
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (h, m, d)) * 0.5

@@ -58,9 +58,9 @@ class TestForwardParity:
                                    atol=1e-5, rtol=1e-5)
 
     def test_single_tile_and_multi_tile_agree(self):
-        q, k, v = _qkv(h=2, m=8, n=96, d=8)
-        y1 = flare_mixer_packed(q, k, v, block_n=96)
-        y2 = flare_mixer_packed(q, k, v, block_n=16)
+        q, k, v = _qkv(h=2, m=8, n=300, d=8)
+        y1 = flare_mixer_packed(q, k, v, block_n=300)
+        y2 = flare_mixer_packed(q, k, v, block_n=128)   # 3 tiles, the last partial
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5, rtol=1e-5)
 
 
@@ -103,22 +103,38 @@ class TestGradParity:
         assert bool(jnp.isfinite(f(q)).all())
 
 
-# grids with several groups (B*Gh >= 3) and N blocks (>= 3), so that the
-# per-group scratch is rebuilt and the sweep runs first, steady and last
-# blocks; N padded to the block and not, every pack, and latent padding
+# grids with several groups (B*Gh >= 3) and N blocks (>= 3 of 128 tokens),
+# so that the per-group scratch is rebuilt and the sweep runs first, steady
+# and last blocks; a partial last block and not, every pack, latent
+# padding, and the shapes whose pack*D channel rows are no legal sublane
+# block (bfloat16 at pack*D = 8, float32 at pack*D = 4), which read the
+# whole channel slab
 SWEEP_CASES = {
-    "pack1_Npad": dict(b=2, h=3, m=16, n=70, pack=1),
-    "pack1_Neven": dict(b=2, h=3, m=16, n=64, pack=1),
-    "pack2_Npad": dict(b=2, h=4, m=16, n=50, pack=2),
-    "pack2_Neven": dict(b=2, h=4, m=16, n=48, pack=2),
-    "pack4_Npad": dict(b=3, h=4, m=16, n=40, pack=4),
-    "pack4_Neven": dict(b=3, h=4, m=16, n=48, pack=4),
-    "pack2_Mpad_Npad": dict(b=2, h=3, m=12, n=45, pack=2),
+    "pack1_Npad": dict(b=2, h=3, m=16, n=300, pack=1),
+    "pack1_Neven": dict(b=2, h=3, m=16, n=384, pack=1),
+    "pack2_Npad": dict(b=2, h=4, m=16, n=290, pack=2),
+    "pack2_Neven": dict(b=2, h=4, m=16, n=384, pack=2),
+    "pack4_Npad": dict(b=3, h=4, m=16, n=270, pack=4),
+    "pack4_Neven": dict(b=3, h=4, m=16, n=384, pack=4),
+    "pack2_Mpad_Npad": dict(b=2, h=3, m=12, n=301, pack=2),
     # token tiles that the lane-replicated row statistics are widened to:
-    # 208 lanes (block_n 256 over N = 200, not a multiple of 128) and 128
-    "pack2_bn208": dict(b=2, h=2, m=16, n=200, pack=2, block_n=256),
+    # the whole N of 208 (block_n 256 covers it; not a multiple of 128)
+    # and 128
+    "pack2_bn208": dict(b=2, h=2, m=16, n=208, pack=2, block_n=256),
     "pack2_bn128": dict(b=2, h=2, m=16, n=300, pack=2, block_n=128),
+    "slab_bf16_pack1": dict(b=2, h=3, m=16, n=300, pack=1, dtype=jnp.bfloat16),
+    "slab_D4_pack1": dict(b=2, h=3, m=16, n=300, pack=1, d=4),
 }
+
+
+def _sweep_case(case):
+    """(pack, block_n, dtype, q, k, v) of a SWEEP_CASES entry."""
+    case = dict(case)
+    pack = case.pop("pack")
+    block_n = case.pop("block_n", 128)
+    case.setdefault("d", 8)
+    dtype = case.setdefault("dtype", jnp.float32)
+    return pack, block_n, dtype, *_qkv(**case)
 
 
 class TestSweepInvariants:
@@ -127,12 +143,12 @@ class TestSweepInvariants:
 
     @pytest.mark.parametrize("case", SWEEP_CASES.values(), ids=SWEEP_CASES.keys())
     def test_forward_and_grads_match_materialized(self, case):
-        case = dict(case)
-        pack = case.pop("pack")
-        block_n = case.pop("block_n", 16)
-        q, k, v = _qkv(d=8, **case)
+        pack, block_n, dtype, q, k, v = _sweep_case(case)
         w = jax.random.normal(jax.random.fold_in(KEY, 13), v.shape)
         ref_pol = MixerPolicy(backends=("materialized",))
+        f32 = lambda x: np.asarray(x.astype(jnp.float32))
+        # fp32 at the acceptance bar; bfloat16 at its bar in TestForwardParity
+        tol_y, tol_g = (1e-5, 1e-4) if dtype == jnp.float32 else (5e-2, 5e-2)
 
         def packed(q, k, v):
             return flare_mixer_packed(q, k, v, pack=pack, block_n=block_n)
@@ -140,16 +156,16 @@ class TestSweepInvariants:
         def ref(q, k, v):
             return flare_mixer(q, k, v, policy=ref_pol)
 
-        np.testing.assert_allclose(np.asarray(packed(q, k, v)),
-                                   np.asarray(ref(q, k, v)),
-                                   atol=1e-5, rtol=1e-5)
-        gp = jax.grad(lambda *a: jnp.sum(w * packed(*a)), argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda *a: jnp.sum(w * ref(*a)), argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(f32(packed(q, k, v)), f32(ref(q, k, v)),
+                                   atol=tol_y, rtol=tol_y)
+        loss = lambda f: lambda *a: jnp.sum(w * f(*a).astype(jnp.float32))
+        gp = jax.grad(loss(packed), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
         for got, want in zip(gp, gr):
-            scale = np.abs(np.asarray(want)).max() + 1e-12
-            np.testing.assert_allclose(np.asarray(got) / scale,
-                                       np.asarray(want) / scale,
-                                       atol=1e-4, rtol=1e-4)
+            assert got.dtype == want.dtype == dtype
+            scale = np.abs(f32(want)).max() + 1e-12
+            np.testing.assert_allclose(f32(got) / scale, f32(want) / scale,
+                                       atol=tol_g, rtol=tol_g)
 
     @pytest.mark.parametrize("launch", ["flare_packed_fwd", "flare_packed_bwd"])
     def test_steady_steps_build_no_masks(self, launch):
@@ -157,9 +173,9 @@ class TestSweepInvariants:
         the steady state (a middle N block, either phase) executes no
         integer div/rem and no 2-D iota; the group's first step and the
         last block do (the group-init and last-block ``pl.when``s)."""
-        q, k, v = _qkv(h=4, m=16, n=70, d=8)         # 5 blocks of 16, padded
+        q, k, v = _qkv(h=4, m=16, n=570, d=8)        # 5 blocks of 128, partial
         jaxpr = jax.make_jaxpr(jax.grad(
-            lambda q, k, v: jnp.sum(flare_mixer_packed(q, k, v, pack=2, block_n=16)),
+            lambda q, k, v: jnp.sum(flare_mixer_packed(q, k, v, pack=2, block_n=128)),
             argnums=(0, 1, 2)))(q, k, v)
         kernel = _pallas_kernel_jaxpr(jaxpr.jaxpr, launch)
         for phase in (0, 1):
@@ -171,39 +187,31 @@ class TestSweepInvariants:
     def test_saved_row_statistics(self, case):
         """The forward's saved ``mx`` and ``den`` (compacted once per group
         from the sweep's lane-replicated statistics) are the row max and
-        the row sum of exp over the padded score matrix."""
+        the row sum of exp over the score matrix of the real tokens, read
+        from channel-major [B, C, N] K and V."""
         from repro.kernels import flare_packed as fp
 
-        case = dict(case)
-        pack = case.pop("pack")
-        block_n = case.pop("block_n", 16)
-        q, k, v = _qkv(d=8, **case)
+        pack, block_n, dtype, q, k, v = _sweep_case(case)
         b, h, n, d = k.shape
         m = q.shape[1]
         gh = -(-h // pack)
         mp = fp._round_up(m, 16)
-        wl = fp._round_up(pack * d, fp.LANE)
-        bn = min(block_n, fp._round_up(n, 16))
-        np_ = fp._round_up(n, bn)
+        bn = fp._token_tile(n, block_n)
         q_pad = fp._pad_axis(fp._pad_axis(q, 0, gh * pack), 1, mp)
-        k_pad = fp._pad_axis(fp._pad_axis(k, 1, gh * pack), 2, np_)
-        v_pad = fp._pad_axis(fp._pad_axis(v, 1, gh * pack), 2, np_)
         cfg = fp._PackedCfg(pack=pack, mp=mp, d=d, block_n=bn,
-                            n_valid=n if n < np_ else None,
+                            n_valid=n if n % bn else None,
                             m_valid=m if m < mp else None, interpret=True)
         _, (*_, mx, den, _) = fp._packed_core_fwd(
-            cfg, gh, fp._pack_heads(q_pad, gh, pack, wl),
-            fp._pack_heads(k_pad, gh, pack, wl).reshape(b * gh, np_, wl),
-            fp._pack_heads(v_pad, gh, pack, wl).reshape(b * gh, np_, wl))
+            cfg, gh, fp._pack_heads(q_pad, gh, pack, pack * d),
+            fp._channel_major(k, gh * pack), fp._channel_major(v, gh * pack))
 
-        s = jnp.einsum("hid,bhjd->bhij", q_pad, k_pad,
+        k_pad = fp._pad_axis(k, 1, gh * pack)
+        s = jnp.einsum("hid,bhjd->bhij", q_pad.astype(jnp.float32),
+                       k_pad.astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
-        ok = (jnp.arange(mp) < m)[:, None] & (jnp.arange(np_) < n)[None, :]
-        s = jnp.where(ok, s, fp.NEG_INF)
+        s = jnp.where((jnp.arange(mp) < m)[:, None], s, fp.NEG_INF)
         want_mx = jnp.max(s, axis=-1)                          # [B, Hp, Mp]
-        want_den = jnp.sum(jnp.where(jnp.arange(np_) < n,
-                                     jnp.exp(s - want_mx[..., None]), 0.0),
-                           axis=-1)
+        want_den = jnp.sum(jnp.exp(s - want_mx[..., None]), axis=-1)
         as_saved = lambda x: x.reshape(b * gh, 1, pack * mp)  # head g*pack+p
         np.testing.assert_allclose(np.asarray(mx), np.asarray(as_saved(want_mx)),
                                    rtol=1e-6)
@@ -219,12 +227,12 @@ class TestSweepInvariants:
         statistics as [S, 128] lanes: every reduction over the token axis
         keeps its axis, no rank-1 [S] value is broadcast across a tile, and
         no other rank-1 [S] value (such as a read of [S] scratch) exists."""
-        q, k, v = _qkv(h=4, m=16, n=70, d=8)         # 5 blocks of 16, padded
+        q, k, v = _qkv(h=4, m=16, n=570, d=8)        # 5 blocks of 128, partial
         jaxpr = jax.make_jaxpr(jax.grad(
-            lambda q, k, v: jnp.sum(flare_mixer_packed(q, k, v, pack=2, block_n=16)),
+            lambda q, k, v: jnp.sum(flare_mixer_packed(q, k, v, pack=2, block_n=128)),
             argnums=(0, 1, 2)))(q, k, v)
         kernel = _pallas_kernel_jaxpr(jaxpr.jaxpr, launch)
-        rows, bn = 2 * 16, 16                          # S = pack * Mp
+        rows, bn = 2 * 16, 128                         # S = pack * Mp
         reductions, dropped, broadcasts, other = 0, [], [], []
         for body, eqn in _step_eqns(kernel, (1, phase, 2)):
             name = eqn.primitive.name
@@ -252,16 +260,36 @@ class TestSweepInvariants:
         assert broadcasts == [], broadcasts
         assert other == [], other
 
-    @pytest.mark.parametrize("n, share", [(70, 1 / 5), (64, 0.0)],
+    @pytest.mark.parametrize("n, share", [(570, 1 / 5), (640, 0.0)],
                              ids=["Npad", "Neven"])
     def test_masked_block_share_gauge(self, n, share):
         q, k, v = _qkv(h=2, m=16, n=n, d=8)
-        for fn in (lambda q, k, v: flare_mixer_packed(q, k, v, block_n=16),
+        for fn in (lambda q, k, v: flare_mixer_packed(q, k, v, block_n=128),
                    jax.grad(lambda q, k, v: jnp.sum(
-                       flare_mixer_packed(q, k, v, block_n=16)))):
+                       flare_mixer_packed(q, k, v, block_n=128)))):
             REGISTRY.gauge("flare_packed.masked_block_share").set(-1.0)
             jax.make_jaxpr(fn)(q, k, v)                # builds the launch(es)
             assert REGISTRY.get("flare_packed.masked_block_share").value == share
+
+    # the flare_pde.train_40k mixer (B 8, H 8, N 40,000, M 2,048, D 8, pack
+    # 1, 256-token tiles): K, V and y cross HBM at their own size, 157
+    # blocks spanning 40,192 tokens; and a whole-slab shape (D 4 at pack 1,
+    # 8 groups), whose K and V each group reads in full
+    @pytest.mark.parametrize("shape, factor", [
+        (dict(b=8, h=8, n=40000, d=8, m=2048, dtype=jnp.float32), 40192 / 40000),
+        (dict(b=2, h=8, n=1000, d=4, m=64, dtype=jnp.float32, pack=1),
+         (2 * 8 + 1) / 3 * 1024 / 1000),
+    ], ids=["flare_pde_cell", "slab_D4_pack1"])
+    def test_operand_pad_factor_gauge(self, shape, factor):
+        shape = dict(shape)
+        pack = shape.pop("pack", None)
+        b, h, n, d, m, dt = (shape[x] for x in ("b", "h", "n", "d", "m", "dtype"))
+        q = jax.ShapeDtypeStruct((h, m, d), dt)
+        k = jax.ShapeDtypeStruct((b, h, n, d), dt)
+        REGISTRY.gauge("flare_packed.operand_pad_factor").set(-1.0)
+        jax.make_jaxpr(lambda q, k, v: flare_mixer_packed(q, k, v, pack=pack))(q, k, k)
+        got = REGISTRY.get("flare_packed.operand_pad_factor").value
+        assert got == pytest.approx(factor, rel=1e-12)
 
 
 def _pallas_kernel_jaxpr(jaxpr, name):
@@ -379,6 +407,21 @@ class TestPackAutotune:
         assert heuristic_pack(8, 64, 64) == 2           # 2 * 64 = 128 lanes
         assert heuristic_pack(8, 64, 128) == 1          # nothing to pack
         assert heuristic_pack(32, 2048, 4) <= 2048 // 64  # VMEM row budget
+
+    @pytest.mark.parametrize("tokens, tiles", [
+        (100, [100]), (300, [128, 256, 300]),
+        (40000, [128, 256, 512, 1024])])
+    def test_candidate_tiles_are_lane_tiles(self, tokens, tiles):
+        """With tokens on lanes a tile narrower than N is a multiple of 128,
+        else it is the whole N: every candidate is a tile the wrapper runs
+        as given."""
+        from repro.backends import autotune
+        from repro.kernels.flare_packed import _token_tile
+
+        shape = dispatch.MixerShape(1, 8, tokens, 64, 8)
+        got = sorted({c["block_n"] for c in autotune.packed_candidates(shape)})
+        assert got == tiles
+        assert all(_token_tile(tokens, bn) == bn for bn in got)
 
     def test_packed_kind_cache_roundtrip(self, tmp_path, monkeypatch):
         from repro.backends import autotune

@@ -18,6 +18,7 @@ a module-scoped fixture (never at import time) and the tests stay in this
 one file; nothing runs, so no time or result is checked here.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,12 @@ PACKED = {"pack8": dict(PDE, H=16, M=256), "tile208": dict(PDE, N=200)}
 # qwen2_1_5b decode read (configs/qwen2_1_5b.py): 8 slots x 2048 capacity
 # over a 16,384-token pool of 16-token pages (+ the trash page)
 QWEN = dict(B=8, H=2, G=6, D=128, block=16, NB=1025, P=128)
+
+
+# an HLO instruction that moves a whole array: its name, type and opcode
+_LAYOUT_OP = re.compile(r"\s*(?:ROOT\s+)?%?\S+\s*=\s*(?P<dt>\w+)\[(?P<dims>[\d,]*)\]"
+                        r"\S*\s+(?:copy|transpose|pad)\(")
+_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "f16": 2, "s8": 1, "pred": 1}
 
 
 @pytest.fixture(scope="module")
@@ -84,6 +91,38 @@ def test_flare_packed_compiles_at_flare_pde_widths(one_chip, mode):
     k = s((PDE["B"], PDE["H"], PDE["N"], PDE["D"]))
     f = lambda q, k, v: flare_mixer_packed(q, k, v, interpret=False)
     _compile(f if mode == "forward" else _grad(f), q, k, k)
+
+
+@pytest.mark.parametrize("mode", ["forward", "grad"])
+def test_flare_packed_moves_no_padded_copy_of_the_activations(one_chip, mode):
+    """The packed kernels read K and V and write y channel-major, the layout
+    the model's [B, N, C] activations already have in HBM: compiled between
+    ``core/flare.py``'s head split and merge, the mixer (and its gradient)
+    holds no copy, transpose or pad larger than 1.01x one [B, N, C]
+    activation."""
+    from repro.core.flare import _merge_heads, _split_heads
+    from repro.kernels.flare_packed import flare_mixer_packed
+
+    b, h, n, d, m = (PDE[x] for x in ("B", "H", "N", "D", "M"))
+    s = lambda dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def mixer(q, xk, xv):
+        return _merge_heads(flare_mixer_packed(
+            q, _split_heads(xk, h), _split_heads(xv, h), interpret=False))
+
+    f = mixer if mode == "forward" else jax.grad(
+        lambda *a: jnp.sum(mixer(*a) ** 2), argnums=(0, 1, 2))
+    text = _compile(f, s((h, m, d)), s((b, n, h * d)), s((b, n, h * d))).as_text()
+    limit = 1.01 * b * n * h * d * 4
+    big = []
+    for line in text.splitlines():
+        op = _LAYOUT_OP.match(line)
+        if op:
+            dims = [int(x) for x in op.group("dims").split(",") if x]
+            nbytes = np.prod(dims, dtype=np.int64) * _BYTES.get(op.group("dt"), 4)
+            if nbytes > limit:
+                big.append(line.strip()[:120])
+    assert big == [], big
 
 
 @pytest.mark.parametrize("shape", PACKED.values(), ids=PACKED.keys())
